@@ -8,7 +8,12 @@
   roofline_table   — EXPERIMENTS.md §Roofline from the dry-run artifacts
 
 Each module's ``run()`` returns [(name, us_per_call, derived), ...]; the
-harness prints the combined CSV.
+harness prints the combined CSV.  Every module runs in this one process
+(a chip belongs to the process that first touches jax); on a CPU host,
+fig7 needs placeholder devices given before the run:
+
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      PYTHONPATH=src python -m benchmarks.run
 """
 import sys
 import traceback

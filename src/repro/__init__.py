@@ -3,11 +3,3 @@
 User scripts go through ``repro.api`` (``api.load(arch) -> Session``);
 everything else is runtime the Session owns.
 """
-import jax as _jax
-
-# Old jax (no native shard_map) also predates the sharding-invariant
-# threefry default; without it, parameter init *values* change with the
-# param sharding (fsdp vs replicated), breaking the transparency guarantee
-# that distribution is invisible to numerics.  Align with new-jax defaults.
-if not hasattr(_jax, "shard_map"):
-    _jax.config.update("jax_threefry_partitionable", True)

@@ -164,13 +164,19 @@ class Session:
             import jax
             from repro.launch.mesh import build_mesh
             need = self.mesh_cfg.num_devices
-            have = len(jax.devices())
+            devices = jax.devices()
+            have = len(devices)
             if need > have:
+                if devices[0].platform == "cpu":
+                    hint = ("set XLA_FLAGS=--xla_force_host_platform_device_"
+                            "count=N (before the first jax import) or pass "
+                            "--devices to the launchers")
+                else:
+                    hint = (f"this host has {have} {devices[0].device_kind} "
+                            f"device(s); use a mesh of at most {have}")
                 raise ValueError(
                     f"mesh {self.mesh_cfg.shape} needs {need} devices but "
-                    f"only {have} are visible; set XLA_FLAGS="
-                    "--xla_force_host_platform_device_count=N (before the "
-                    "first jax import) or pass --devices to the launchers")
+                    f"only {have} are visible; {hint}")
             self._mesh = build_mesh(self._train_mesh_cfg())
         return self._mesh
 
@@ -182,8 +188,15 @@ class Session:
             return self._train_state.params
         if self._params is None:
             import jax
-            self._params = self.bundle.init_params(
-                jax.random.PRNGKey(self.seed))
+            init = self.bundle.init_params
+            if self.mesh_cfg is not None:
+                # initialize straight into the mesh layout: a full copy on
+                # one device would not fit beside its shards at real size
+                from repro.models import common
+                init = jax.jit(init, out_shardings=common.logical_to_mesh(
+                    self.bundle.specs, self.mesh,
+                    common.rules_for(self.mesh_cfg, self.model)))
+            self._params = init(jax.random.PRNGKey(self.seed))
         return self._params
 
     # -- train -------------------------------------------------------------
@@ -369,6 +382,12 @@ class Session:
             self._drop_engine(next(iter(self._engines)))
         self._last_engine = eng
         return eng
+
+    @property
+    def trainer(self):
+        """The transparent trainer of the last ``train`` call (None
+        before): its ``lower_step`` shows the compiled step."""
+        return self._trainer
 
     @property
     def engine(self):

@@ -341,7 +341,8 @@ class ServeConfig:
     trace: bool = False                # repro.obs engine tracing (fenced)
     trace_capacity: int = 1 << 16      # trace ring-buffer bound (events)
     # Pallas paged-attention kernels (decode + chunked prefill + verify).
-    # None = auto (kernels on TPU, jnp gather path elsewhere); True forces
+    # None = auto (kernels on one TPU device, jnp gather path elsewhere,
+    # a multi-device mesh included — see the engine); True forces
     # the kernels everywhere (interpret mode off-TPU — slow but correct,
     # what the CI smoke job and the kernel-identity tests run); False
     # forces the jnp gather path even on TPU.

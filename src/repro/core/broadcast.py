@@ -27,14 +27,12 @@ def _is_rank_zero(axes: Sequence[str]):
     return flag
 
 
-def broadcast_masked(tree, axes: Sequence[str], mask):
-    """Masked-psum broadcast: the replica(s) where ``mask`` is True
-    contribute, everyone receives the sum.  ``mask`` lets callers supply
-    rank identity as *data* (e.g. a sharded arange) — required under
-    partial-auto shard_map on jax 0.4.x, where ``axis_index`` cannot be
-    SPMD-partitioned."""
+def broadcast_from_rank0(tree, axes: Sequence[str]):
+    """Inside a shard_map manual region: replace every leaf with rank 0's
+    (masked psum: only rank 0 contributes, everyone receives the sum)."""
     if not axes:
         return tree
+    mask = _is_rank_zero(axes)
 
     def one(x):
         contrib = jnp.where(mask, x.astype(jnp.float32), 0.0)
@@ -42,13 +40,6 @@ def broadcast_masked(tree, axes: Sequence[str], mask):
         return total.astype(x.dtype)
 
     return jax.tree.map(one, tree)
-
-
-def broadcast_from_rank0(tree, axes: Sequence[str]):
-    """Inside a shard_map manual region: replace every leaf with rank 0's."""
-    if not axes:
-        return tree
-    return broadcast_masked(tree, axes, _is_rank_zero(axes))
 
 
 def replicas_identical(tree, axes: Sequence[str]):

@@ -37,7 +37,6 @@ import numpy as np
 from repro.configs.base import MeshConfig, RunConfig
 from repro.core import allreduce as ar
 from repro.core import broadcast as bc
-from repro.core import compat
 from repro.models import common
 from repro.optim.optimizers import (Optimizer, clip_by_global_norm,
                                     global_norm, make_optimizer)
@@ -97,33 +96,29 @@ def _linear_dp_rank(axes: Tuple[str, ...]):
     return r
 
 
-def _rank_scalar(axes: Tuple[str, ...], rank):
-    """Linear DP rank: from the sharded rank input when provided (required
-    under partial-auto on jax 0.4.x — axis_index won't partition), else
-    derived from axis_index."""
-    return rank[0] if rank is not None else _linear_dp_rank(axes)
-
-
-def _scatter_mean_vec(vec, axes: Tuple[str, ...], pad_to: int, dp: int,
-                      rank=None):
-    """reduce-scatter(mean) of a flat fp32 vector -> local [pad_to/dp] shard."""
+def _scatter_mean_vec(vec, axes: Tuple[str, ...], pad_to: int, dp: int):
+    """reduce-scatter(mean) of a flat fp32 vector -> local [pad_to/dp] shard.
+    Scattering over each axis in turn composes the sum in linear-rank
+    order (see ``_linear_dp_rank``)."""
     v = jnp.pad(vec, (0, pad_to - vec.size))
-    v = compat.psum_scatter_vec(v, axes, _rank_scalar(axes, rank),
-                                pad_to // dp)
+    for a in axes:
+        v = jax.lax.psum_scatter(v, a, scatter_dimension=0, tiled=True)
     return v / dp
 
 
-def _gather_vec(shard, axes: Tuple[str, ...], pad_to: int, rank=None):
-    return compat.all_gather_vec(shard, axes, _rank_scalar(axes, rank),
-                                 pad_to)
+def _gather_vec(shard, axes: Tuple[str, ...]):
+    """Inverse of ``_scatter_mean_vec``'s scatter: the full flat vector."""
+    for a in reversed(axes):
+        shard = jax.lax.all_gather(shard, a, axis=0, tiled=True)
+    return shard
 
 
-def _local_param_shard(params, axes, pad_to: int, dp: int, rank=None):
+def _local_param_shard(params, axes, pad_to: int, dp: int):
     """This rank's slice of the flat parameter vector (no communication)."""
     vec = _flatten_to_vec(params)
     vec = jnp.pad(vec, (0, pad_to - vec.size))
     shard_size = pad_to // dp
-    r = _rank_scalar(axes, rank)
+    r = _linear_dp_rank(axes)
     return jax.lax.dynamic_slice(vec, (r * shard_size,), (shard_size,))
 
 
@@ -153,26 +148,6 @@ def value_and_grad(loss_fn, *, strategy: str = "layerwise",
         return (jax.lax.pmean(loss, tuple(axes)) if axes else loss), grads
 
     return wrapped
-
-
-class _RankedStepFn:
-    """Compiled step closure that feeds the DP-rank input (rank-as-data;
-    see ``_dp_ranks``) while keeping the public ``(state, batch)`` call and
-    ``lower(state, batch)`` dry-run surfaces unchanged."""
-
-    def __init__(self, jitted, ranks, rank_sharding):
-        self._jitted = jitted
-        self._ranks = ranks
-        self._rank_sharding = rank_sharding
-
-    def __call__(self, state, batch):
-        return self._jitted(state, batch, self._ranks)
-
-    def lower(self, state_structs, batch_structs):
-        rank_struct = jax.ShapeDtypeStruct(
-            self._ranks.shape, self._ranks.dtype,
-            sharding=self._rank_sharding)
-        return self._jitted.lower(state_structs, batch_structs, rank_struct)
 
 
 # ---------------------------------------------------------------------------
@@ -210,22 +185,10 @@ class TransparentTrainer:
         self.dp = int(np.prod([s for s, a in zip(self.mesh_cfg.shape,
                                                  self.mesh_cfg.axis_names)
                                if a in ("pod", "data")])) or 1
-        msize = int(np.prod([s for s, a in zip(self.mesh_cfg.shape,
-                                               self.mesh_cfg.axis_names)
-                             if a == "model"])) or 1
-        # The paper-faithful manual region keeps the "model" axis auto
-        # (GSPMD tensor parallelism).  Old jax cannot lower such partial-
-        # auto regions (core.compat): go *fully* manual when the mesh is
-        # pure-DP (model extent 1 — the paper's actual setting), otherwise
-        # fall back to the GSPMD auto lowering (numerically equivalent;
-        # the allreduce decomposition is then XLA's choice, not ours).
+        # The paper-faithful manual region is manual over the DP axes and
+        # keeps the "model" axis auto (GSPMD tensor parallelism).
         if self.mesh_cfg.dp_mode == "replicated" and self.dp_axes:
-            if compat.partial_auto_ok():
-                self._manual_axes = set(self.dp_axes)
-            elif msize == 1:
-                self._manual_axes = set(self.mesh_cfg.axis_names)
-            else:
-                self._manual_axes = None          # auto fallback
+            self._manual_axes = set(self.dp_axes)
         else:
             self._manual_axes = None
         self._zero1 = (self.mesh_cfg.allreduce == "reduce_scatter"
@@ -262,12 +225,6 @@ class TransparentTrainer:
             return jax.tree.map(
                 lambda l: P(dp_tuple, None) if l.ndim == 2 else P(), struct)
         return jax.tree.map(lambda _: P(), struct)
-
-    def _dp_ranks(self):
-        """[dp] int32 linear ranks; sharded over the DP axes each replica's
-        manual-region slice is its own rank — rank identity as data (see
-        core.compat: axis_index can't lower under partial-auto on old jax)."""
-        return jnp.arange(self.dp, dtype=jnp.int32)
 
     def param_shardings(self):
         return common.logical_to_mesh(self.param_specs, self.mesh, self.rules)
@@ -328,29 +285,27 @@ class TransparentTrainer:
             err_specs = (jax.tree.map(lambda s: s, pspecs)
                          if mesh_cfg.allreduce == "compressed" else None)
 
-            def _init_inner(key, rank):
+            def _init_inner(key):
                 params, err = _base_state(key)
                 # paper §III-D.1: rank-0 broadcast guarantees identical replicas
-                params = bc.broadcast_masked(params, self.dp_axes,
-                                             rank[0] == 0)
+                params = bc.broadcast_from_rank0(params, self.dp_axes)
                 if self._zero1:
                     shard = _local_param_shard(params, self.dp_axes,
-                                               self._padded, self.dp,
-                                               rank=rank)
+                                               self._padded, self.dp)
                     opt = self.opt.init({"flat": shard[None, :]})
                 else:
                     opt = self.opt.init(params)
                 return TrainState(params=params, opt=opt, err=err,
                                   step=jnp.zeros((), jnp.int32))
 
-            smapped = compat.shard_map(
+            smapped = jax.shard_map(
                 _init_inner, mesh=self.mesh,
-                in_specs=(P(), P(tuple(self.dp_axes))),
+                in_specs=(P(),),
                 out_specs=TrainState(params=pspecs, opt=opt_specs,
                                      err=err_specs, step=P()),
                 check_vma=False, axis_names=self._manual_axes)
             fn = jax.jit(smapped, out_shardings=self.state_shardings())
-            return fn(jax.random.PRNGKey(seed), self._dp_ranks())
+            return fn(jax.random.PRNGKey(seed))
         else:
             def _init_auto(key):
                 params, err = _base_state(key)
@@ -385,7 +340,7 @@ class TransparentTrainer:
             acc_body, (zeros, jnp.zeros((), jnp.float32)), stacked)
         return loss / n_micro, jax.tree.map(lambda g: g / n_micro, grads)
 
-    def _local_step(self, state: TrainState, batch, rank=None):
+    def _local_step(self, state: TrainState, batch):
         """Single-replica semantics + injected collectives (manual region)."""
         run_cfg, mesh_cfg = self.run_cfg, self.mesh_cfg
         loss, grads = self._accumulate(state, batch)
@@ -395,18 +350,17 @@ class TransparentTrainer:
             # ZeRO-1: RS(mean) + sharded optimizer + AG (beyond-paper)
             vec = _flatten_to_vec(grads)
             gshard = _scatter_mean_vec(vec, self.dp_axes, self._padded,
-                                       self.dp, rank=rank)
+                                       self.dp)
             sq = jax.lax.psum(jnp.sum(jnp.square(gshard)), tuple(self.dp_axes))
             gn = jnp.sqrt(sq)
             if run_cfg.optimizer.grad_clip:
                 gshard = gshard * jnp.minimum(
                     1.0, run_cfg.optimizer.grad_clip / jnp.maximum(gn, 1e-12))
             pshard = _local_param_shard(state.params, self.dp_axes,
-                                        self._padded, self.dp, rank=rank)
+                                        self._padded, self.dp)
             new_pshard, new_opt = self.opt.update(
                 {"flat": gshard[None, :]}, state.opt, {"flat": pshard[None, :]})
-            new_vec = _gather_vec(new_pshard["flat"][0], self.dp_axes,
-                                  self._padded, rank=rank)
+            new_vec = _gather_vec(new_pshard["flat"][0], self.dp_axes)
             new_params = _unflatten_from_vec(new_vec[:self._n_params],
                                              state.params)
         else:
@@ -443,17 +397,13 @@ class TransparentTrainer:
                 step=P())
             bspecs = _batch_specs_tree(batch_like, self.dp_axes)
             metric_specs = {"loss": P(), "grad_norm": P(), "step": P()}
-            dp_tuple = tuple(self.dp_axes)
-            smapped = compat.shard_map(
+            smapped = jax.shard_map(
                 self._local_step, mesh=self.mesh,
-                in_specs=(state_specs, bspecs, P(dp_tuple)),
+                in_specs=(state_specs, bspecs),
                 out_specs=(state_specs, metric_specs),
                 check_vma=False, axis_names=self._manual_axes)
-            jfn = jax.jit(smapped,
-                          in_shardings=(state_sh, batch_sh,
-                                        self._ns(P(dp_tuple))),
-                          out_shardings=(state_sh, None), donate_argnums=(0,))
-            fn = _RankedStepFn(jfn, self._dp_ranks(), self._ns(P(dp_tuple)))
+            fn = jax.jit(smapped, in_shardings=(state_sh, batch_sh),
+                         out_shardings=(state_sh, None), donate_argnums=(0,))
         else:
             # fsdp / auto mode: XLA derives reduce-scatter/all-gather from the
             # 2-D parameter sharding (beyond-paper ZeRO-3)

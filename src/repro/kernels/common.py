@@ -1,11 +1,7 @@
-"""Shared constants/shims for the Pallas kernel families."""
+"""Shared constants for the Pallas kernel families."""
 from __future__ import annotations
 
 import jax.numpy as jnp
-import jax.experimental.pallas.tpu as pltpu
 
 # Large-negative mask value safe to exponentiate in fp32 (exp -> exactly 0)
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
-
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams

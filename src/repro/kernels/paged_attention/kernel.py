@@ -51,17 +51,26 @@ bucket no longer pays full attention tiles for its padding rows) and
 (b) pages past each query block's causal horizon.
 
 Layouts (see ref.py): q [slots, KV, G, hd] (prefill: [S, KV, G, hd]);
-k/v pages [P, ps, KV, hd]; q_lat [slots, H, R]; ckv pages [P, ps, R];
-page_table [slots, n_table] int32 (prefill: one row [n_table]); lengths
-[slots] int32 (prefill: meta [2] int32 = start, n_valid).
+k/v pages [P, KV, ps, hd] (head-major); q_lat [slots, H, R]; ckv pages
+[P, ps, R]; page_table [slots, n_table] int32 (prefill: one row
+[n_table]); lengths [slots] int32 (prefill: meta [2] int32 = start,
+n_valid).
 
-Quantized (int8) pools add ``k_scale``/``v_scale`` operands [P, ps, KV]
-fp32 — one symmetric scale per (page, offset, kv-head) row — streamed
-through the same page-table index maps as their int8 data pages.  Dequant
-fuses into the online softmax: raw int8 scores are multiplied by the key's
-scale per column, probabilities by the value's scale per row before the PV
-product — fp pages are never materialized, so HBM reads stay ~1/4 of the
-fp pool's.  The jnp oracles in ref.py apply the identical fused math
+Block shapes obey the TPU rule that the last two dims of a block are
+divisible by (8, 128) or equal the array's: a k/v page block is
+``(1, 1, ps, hd)``, so ``page_size`` must be a multiple of the storage
+dtype's sublane tile (``check_page_size``); prefill queries are handed to
+the kernel head-major as [KV, S*G, hd] so one block is a contiguous
+``(qb*G, hd)`` tile.
+
+Quantized (int8) pools add ``k_scale``/``v_scale`` operands [P, KV, 1, ps]
+bf16 — one symmetric scale per (page, kv-head, offset) row, laid out as a
+lane vector so the block ``(1, 1, 1, ps)`` is legal and multiplies score
+columns directly — streamed through the same page-table index maps as
+their int8 data pages.  Dequant fuses into the online softmax: raw int8
+scores are multiplied by the key's scale per column, probabilities by the
+value's scale per row before the PV product — fp pages are never
+materialized, so HBM reads stay ~1/4 of the fp pool's.  The jnp oracles in ref.py apply the identical fused math
 (same multiply placement), which is what keeps quantized kernel-on vs
 kernel-off token-identical.  MLA latent kernels take no scales (the
 layout seam rejects quantized latents — rank is a contracted dim).
@@ -75,7 +84,35 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.common import NEG_INF, CompilerParams as _CompilerParams
+from repro.kernels.common import NEG_INF
+
+#: sublane tile of a 32-bit row; packed dtypes hold 32 // bits rows per tile
+_SUBLANES = 8
+
+
+def check_page_size(page_size: int, dtype) -> None:
+    """A page block ``(1, 1, ps, hd)`` compiles for TPU only when ``ps`` is
+    a multiple of the dtype's sublane tile: 8 rows for f32, 16 for bf16,
+    32 for int8."""
+    tile = _SUBLANES * 4 // jnp.dtype(dtype).itemsize
+    if page_size % tile:
+        raise ValueError(
+            f"page_size={page_size} is not a multiple of {tile}, the TPU "
+            f"sublane tile of {jnp.dtype(dtype).name} pages: the Pallas "
+            "paged kernels cannot tile it — pick page_size as a multiple "
+            f"of {tile}")
+
+
+def _head_major_q(q):
+    """[S, KV, G, hd] -> [KV, S*G, hd]: row r of head h is query r // G."""
+    S, KV, G, hd = q.shape
+    return q.transpose(1, 0, 2, 3).reshape(KV, S * G, hd)
+
+
+def _token_major_out(o, S: int):
+    """Inverse of ``_head_major_q``."""
+    KV, _, hd = o.shape
+    return o.reshape(KV, S, -1, hd).transpose(1, 0, 2, 3)
 
 
 def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
@@ -105,14 +142,14 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(base < limit)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)               # [G, hd]
-        k = k_ref[0, :, 0].astype(jnp.float32)            # [ps, hd]
-        v = v_ref[0, :, 0].astype(jnp.float32)            # [ps, hd]
+        k = k_ref[0, 0].astype(jnp.float32)               # [ps, hd]
+        v = v_ref[0, 0].astype(jnp.float32)               # [ps, hd]
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [G, ps]
         if quantized:
             # fused dequant: raw int8 scores scaled per key column
-            sc = sc * ks_ref[0, :, 0].astype(jnp.float32)[None, :]
+            sc = sc * ks_ref[0, 0].astype(jnp.float32)    # [1, ps]
         idx = base + jax.lax.broadcasted_iota(
             jnp.int32, sc.shape, 1)                       # cell indices
         if window:
@@ -133,8 +170,7 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         # fused dequant: probabilities scaled per value row (the softmax
         # denominator stays unscaled — it normalizes probabilities, not
         # values)
-        pv = pr * vs_ref[0, :, 0].astype(jnp.float32)[None, :] \
-            if quantized else pr
+        pv = pr * vs_ref[0, 0].astype(jnp.float32) if quantized else pr
         acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -149,17 +185,17 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 def paged_attention_kernel(q, k_pages, v_pages, page_table, lengths, *,
                            window: int = 0, k_scale=None, v_scale=None,
                            interpret: bool = False):
-    """q: [slots, KV, G, hd]; k/v_pages: [P, ps, KV, hd];
+    """q: [slots, KV, G, hd]; k/v_pages: [P, KV, ps, hd];
     page_table: [slots, n_table] int32; lengths: [slots] int32.
     ``window > 0`` selects the ring-cell position mapping.  ``k_scale``/
-    ``v_scale`` [P, ps, KV] bf16 mark int8 pages — their blocks stream
+    ``v_scale`` [P, KV, 1, ps] bf16 mark int8 pages — their blocks stream
     through the same page-table index map and dequant fuses into the
     softmax accumulation.
 
     Returns [slots, KV, G, hd] in q.dtype.
     """
     slots, KV, G, hd = q.shape
-    _, ps, _, _ = k_pages.shape
+    ps = k_pages.shape[2]
     n_table = page_table.shape[1]
     scale = hd ** -0.5
     quantized = k_scale is not None
@@ -168,8 +204,8 @@ def paged_attention_kernel(q, k_pages, v_pages, page_table, lengths, *,
                                n_table=n_table, window=window,
                                quantized=quantized)
 
-    page_spec = pl.BlockSpec((1, ps, 1, hd),
-                             lambda s, h, p, pt, ln: (pt[s, p], 0, h, 0))
+    page_spec = pl.BlockSpec((1, 1, ps, hd),
+                             lambda s, h, p, pt, ln: (pt[s, p], h, 0, 0))
     in_specs = [
         pl.BlockSpec((1, 1, G, hd), lambda s, h, p, pt, ln: (s, h, 0, 0)),
         # physical page chosen by the prefetched table — the paged gather
@@ -178,8 +214,8 @@ def paged_attention_kernel(q, k_pages, v_pages, page_table, lengths, *,
     ]
     operands = [q, k_pages, v_pages]
     if quantized:
-        scale_spec = pl.BlockSpec((1, ps, 1),
-                                  lambda s, h, p, pt, ln: (pt[s, p], 0, h))
+        scale_spec = pl.BlockSpec((1, 1, 1, ps),
+                                  lambda s, h, p, pt, ln: (pt[s, p], h, 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
 
@@ -200,7 +236,7 @@ def paged_attention_kernel(q, k_pages, v_pages, page_table, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, KV, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, lengths, *operands)
@@ -291,7 +327,7 @@ def paged_mla_kernel(q_lat, q_rope, ckv_pages, krope_pages, page_table,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, H, R), q_lat.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, lengths, q_lat, q_rope, ckv_pages, krope_pages)
@@ -309,7 +345,7 @@ def _prefill_q_block(S: int) -> int:
 
 def _online_update(m_scr, l_scr, acc_scr, sc, v, v_scale=None):
     """One masked score block folded into the (m, l, acc) scratch state.
-    ``v_scale`` [ps] marks an int8 value block: probabilities are scaled
+    ``v_scale`` [1, ps] marks an int8 value block: probabilities are scaled
     per value row before the PV product (fused dequant); the softmax
     denominator stays unscaled — it normalizes probabilities, not
     values."""
@@ -319,7 +355,7 @@ def _online_update(m_scr, l_scr, acc_scr, sc, v, v_scale=None):
     corr = jnp.exp(m_prev - m_new)
     pr = jnp.exp(sc - m_new)
     l_scr[...] = l_prev * corr + jnp.sum(pr, axis=1, keepdims=True)
-    pv = pr if v_scale is None else pr * v_scale[None, :]
+    pv = pr if v_scale is None else pr * v_scale
     acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
         pv, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -355,47 +391,45 @@ def _paged_prefill_kernel(pt_ref, meta_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when((q0 < n_valid) & (base < horizon))
     def _compute():
-        hd = q_ref.shape[-1]
-        q = q_ref[:, 0].astype(jnp.float32).reshape(-1, hd)  # [qb*G, hd]
-        k = k_ref[0, :, 0].astype(jnp.float32)               # [ps, hd]
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32)                     # [qb*G, hd]
+        k = k_ref[0, 0].astype(jnp.float32)                  # [ps, hd]
+        v = v_ref[0, 0].astype(jnp.float32)
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # [qb*G, ps]
         if quantized:
             # fused dequant: raw int8 scores scaled per key column
-            sc = sc * ks_ref[0, :, 0].astype(jnp.float32)[None, :]
+            sc = sc * ks_ref[0, 0].astype(jnp.float32)       # [1, ps]
         r = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
         c = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
         qpos = start + q0 + r // groups        # row r = query (r // G)
         kidx = base + c
         sc = jnp.where((kidx < limit) & (kidx <= qpos), sc, NEG_INF)
         _online_update(m_scr, l_scr, acc_scr, sc, v,
-                       vs_ref[0, :, 0].astype(jnp.float32) if quantized else None)
+                       vs_ref[0, 0].astype(jnp.float32) if quantized else None)
 
     @pl.when(p == n_table - 1)
     def _finish():
-        qb, _, G, hd = o_ref.shape
-        o_ref[:, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)) \
-            .reshape(qb, G, hd).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)) \
+            .astype(o_ref.dtype)
 
 
 def paged_prefill_kernel(q, k_pages, v_pages, page_table, meta, *,
                          k_scale=None, v_scale=None,
                          interpret: bool = False):
     """Contiguous-layout chunked prefill.  q: [S, KV, G, hd] — one
-    request's bucketed chunk (post-rope); k/v_pages: [P, ps, KV, hd] —
+    request's bucketed chunk (post-rope); k/v_pages: [P, KV, ps, hd] —
     the pool AFTER the chunk's K/V were scattered in; page_table: [n]
     int32 — this request's row (0-padded tail = trash); meta: [2] int32 =
     (start, n_valid).  Query i holds absolute position ``start + i``;
     padding rows (i >= n_valid) are skipped at grid level and come back 0.
-    ``k_scale``/``v_scale`` [P, ps, KV] bf16 mark int8 pages (fused
+    ``k_scale``/``v_scale`` [P, KV, 1, ps] bf16 mark int8 pages (fused
     dequant, same page-table streaming).
 
     Returns [S, KV, G, hd] in q.dtype.
     """
     S, KV, G, hd = q.shape
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     n_table = page_table.shape[0]
     qb = _prefill_q_block(S)
     scale = hd ** -0.5
@@ -405,18 +439,14 @@ def paged_prefill_kernel(q, k_pages, v_pages, page_table, meta, *,
                                page_size=ps, n_table=n_table, q_block=qb,
                                groups=G, quantized=quantized)
 
-    page_spec = pl.BlockSpec((1, ps, 1, hd),
-                             lambda h, qi, p, pt, mt: (pt[p], 0, h, 0))
-    in_specs = [
-        pl.BlockSpec((qb, 1, G, hd),
-                     lambda h, qi, p, pt, mt: (qi, h, 0, 0)),
-        page_spec,
-        page_spec,
-    ]
-    operands = [q, k_pages, v_pages]
+    page_spec = pl.BlockSpec((1, 1, ps, hd),
+                             lambda h, qi, p, pt, mt: (pt[p], h, 0, 0))
+    q_spec = pl.BlockSpec((1, qb * G, hd), lambda h, qi, p, pt, mt: (h, qi, 0))
+    in_specs = [q_spec, page_spec, page_spec]
+    operands = [_head_major_q(q), k_pages, v_pages]
     if quantized:
-        scale_spec = pl.BlockSpec((1, ps, 1),
-                                  lambda h, qi, p, pt, mt: (pt[p], 0, h))
+        scale_spec = pl.BlockSpec((1, 1, 1, ps),
+                                  lambda h, qi, p, pt, mt: (pt[p], h, 0, 0))
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
 
@@ -424,8 +454,7 @@ def paged_prefill_kernel(q, k_pages, v_pages, page_table, meta, *,
         num_scalar_prefetch=2,
         grid=(KV, S // qb, n_table),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((qb, 1, G, hd),
-                               lambda h, qi, p, pt, mt: (qi, h, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((qb * G, 1), jnp.float32),    # m
             pltpu.VMEM((qb * G, 1), jnp.float32),    # l
@@ -433,15 +462,15 @@ def paged_prefill_kernel(q, k_pages, v_pages, page_table, meta, *,
         ],
     )
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, KV, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((KV, S * G, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, meta, *operands)
-
+    return _token_major_out(out, S)
 
 
 def _paged_ring_prefill_kernel(pt_ref, meta_ref, q_ref, k_ref, v_ref,
@@ -467,8 +496,7 @@ def _paged_ring_prefill_kernel(pt_ref, meta_ref, q_ref, k_ref, v_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def _scores(k):
-        hd = q_ref.shape[-1]
-        q = q_ref[:, 0].astype(jnp.float32).reshape(-1, hd)
+        q = q_ref[0].astype(jnp.float32)                     # [qb*G, hd]
         sc = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # [qb*G, ·]
@@ -484,13 +512,13 @@ def _paged_ring_prefill_kernel(pt_ref, meta_ref, q_ref, k_ref, v_ref,
     @pl.when((p < n_table) & (q0 < n_valid)
              & (base < jnp.minimum(start, window)))
     def _ring():
-        k = k_ref[0, :, 0].astype(jnp.float32)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
         sc, qpos, c = _scores(k)
         if quantized:
             # fused dequant of the int8 snapshot (the chunk operands below
             # are freshly projected fp — never quantized)
-            sc = sc * ks_ref[0, :, 0].astype(jnp.float32)[None, :]
+            sc = sc * ks_ref[0, 0].astype(jnp.float32)
         idx = base + c
         cur = start - 1
         kpos = cur - jnp.mod(cur - idx, window)  # < 0 = never written
@@ -498,7 +526,7 @@ def _paged_ring_prefill_kernel(pt_ref, meta_ref, q_ref, k_ref, v_ref,
         # the window mask drops wrapped-over and out-of-window cells
         sc = jnp.where((kpos >= 0) & (kpos > qpos - window), sc, NEG_INF)
         _online_update(m_scr, l_scr, acc_scr, sc, v,
-                       vs_ref[0, :, 0].astype(jnp.float32) if quantized else None)
+                       vs_ref[0, 0].astype(jnp.float32) if quantized else None)
 
     # --- the chunk's own K/V (freshly projected, NOT read from pages) ---
     j0 = (p - n_table) * page_size
@@ -506,8 +534,8 @@ def _paged_ring_prefill_kernel(pt_ref, meta_ref, q_ref, k_ref, v_ref,
     @pl.when((p >= n_table) & (q0 < n_valid)
              & (j0 < jnp.minimum(n_valid, q0 + q_block)))
     def _chunk():
-        k = ck_ref[:, 0].astype(jnp.float32)                 # [ps, hd]
-        v = cv_ref[:, 0].astype(jnp.float32)
+        k = ck_ref[0].astype(jnp.float32)                    # [ps, hd]
+        v = cv_ref[0].astype(jnp.float32)
         sc, qpos, c = _scores(k)
         j = j0 + c
         kpos = start + j
@@ -517,9 +545,8 @@ def _paged_ring_prefill_kernel(pt_ref, meta_ref, q_ref, k_ref, v_ref,
 
     @pl.when(p == n_table + n_chunk - 1)
     def _finish():
-        qb, _, G, hd = o_ref.shape
-        o_ref[:, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)) \
-            .reshape(qb, G, hd).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)) \
+            .astype(o_ref.dtype)
 
 
 def paged_ring_prefill_kernel(q, k_pages, v_pages, chunk_k, chunk_v,
@@ -528,27 +555,27 @@ def paged_ring_prefill_kernel(q, k_pages, v_pages, chunk_k, chunk_v,
                               interpret: bool = False):
     """Ring-layout (sliding-window/local) chunked prefill,
     snapshot-before-write semantics.  q: [S, KV, G, hd]; k/v_pages:
-    [P, ps, KV, hd] — the pool BEFORE the chunk's writes (the chunk wraps
+    [P, KV, ps, hd] — the pool BEFORE the chunk's writes (the chunk wraps
     onto cells its own early queries still need); chunk_k/chunk_v:
     [S, KV, hd] — the chunk's own post-rope keys/values; page_table: [n]
     int32 — the request's ring of ``window // ps`` cells; meta: [2] int32
     = (start, n_valid).  The grid walks ring cells then chunk blocks; the
     sliding-window mask keeps every wrapped-over snapshot cell out of the
-    scores.  ``k_scale``/``v_scale`` [P, ps, KV] bf16 mark int8 snapshot
+    scores.  ``k_scale``/``v_scale`` [P, KV, 1, ps] bf16 mark int8 snapshot
     pages (fused dequant; the chunk operands stay fp).
     Returns [S, KV, G, hd] in q.dtype.
     """
     S, KV, G, hd = q.shape
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     n_table = page_table.shape[0]
     qb = _prefill_q_block(S)
     scale = hd ** -0.5
     quantized = k_scale is not None
     pad = (-S) % ps                            # block chunk keys at ps
-    if pad:
-        chunk_k = jnp.pad(chunk_k, ((0, pad), (0, 0), (0, 0)))
-        chunk_v = jnp.pad(chunk_v, ((0, pad), (0, 0), (0, 0)))
-    n_chunk = chunk_k.shape[0] // ps
+    # head-major [KV, S + pad, hd]: a chunk block (1, ps, hd) is a page tile
+    chunk_k = jnp.pad(chunk_k.transpose(1, 0, 2), ((0, 0), (0, pad), (0, 0)))
+    chunk_v = jnp.pad(chunk_v.transpose(1, 0, 2), ((0, 0), (0, pad), (0, 0)))
+    n_chunk = chunk_k.shape[1] // ps
 
     kernel = functools.partial(_paged_ring_prefill_kernel, scale=scale,
                                page_size=ps, n_table=n_table,
@@ -560,34 +587,30 @@ def paged_ring_prefill_kernel(q, k_pages, v_pages, chunk_k, chunk_v,
     # repeats one index, which the pipeline dedupes — no extra HBM traffic
     def _page_index(h, qi, p, pt, mt):
         return (jnp.where(p < n_table, pt[jnp.minimum(p, n_table - 1)], 0),
-                0, h, 0)
+                h, 0, 0)
 
     def _chunk_index(h, qi, p, pt, mt):
-        return (jnp.where(p >= n_table, p - n_table, 0), h, 0)
+        return (h, jnp.where(p >= n_table, p - n_table, 0), 0)
 
+    q_spec = pl.BlockSpec((1, qb * G, hd), lambda h, qi, p, pt, mt: (h, qi, 0))
     in_specs = [
-        pl.BlockSpec((qb, 1, G, hd),
-                     lambda h, qi, p, pt, mt: (qi, h, 0, 0)),
-        pl.BlockSpec((1, ps, 1, hd), _page_index),
-        pl.BlockSpec((1, ps, 1, hd), _page_index),
-        pl.BlockSpec((ps, 1, hd), _chunk_index),
-        pl.BlockSpec((ps, 1, hd), _chunk_index),
+        q_spec,
+        pl.BlockSpec((1, 1, ps, hd), _page_index),
+        pl.BlockSpec((1, 1, ps, hd), _page_index),
+        pl.BlockSpec((1, ps, hd), _chunk_index),
+        pl.BlockSpec((1, ps, hd), _chunk_index),
     ]
-    operands = [q, k_pages, v_pages, chunk_k, chunk_v]
+    operands = [_head_major_q(q), k_pages, v_pages, chunk_k, chunk_v]
     if quantized:
-        def _scale_index(h, qi, p, pt, mt):
-            return (jnp.where(p < n_table,
-                              pt[jnp.minimum(p, n_table - 1)], 0), 0, h)
-        in_specs += [pl.BlockSpec((1, ps, 1), _scale_index),
-                     pl.BlockSpec((1, ps, 1), _scale_index)]
+        in_specs += [pl.BlockSpec((1, 1, 1, ps), _page_index),
+                     pl.BlockSpec((1, 1, 1, ps), _page_index)]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(KV, S // qb, n_table + n_chunk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((qb, 1, G, hd),
-                               lambda h, qi, p, pt, mt: (qi, h, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((qb * G, 1), jnp.float32),    # m
             pltpu.VMEM((qb * G, 1), jnp.float32),    # l
@@ -595,14 +618,15 @@ def paged_ring_prefill_kernel(q, k_pages, v_pages, chunk_k, chunk_v,
         ],
     )
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, KV, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((KV, S * G, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, meta, *operands)
+    return _token_major_out(out, S)
 
 
 def _paged_mla_prefill_kernel(pt_ref, meta_ref, ql_ref, qr_ref, ckv_ref,
@@ -697,7 +721,7 @@ def paged_mla_prefill_kernel(q_lat, q_rope, ckv_pages, krope_pages,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, R), q_lat.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, meta, q_lat, q_rope, ckv_pages, krope_pages)

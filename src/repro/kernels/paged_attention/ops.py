@@ -19,9 +19,10 @@ semantics; the chunk's own K/V ride along), ``paged_mla_prefill``
 (absorbed latent queries, latent output).  All share the head conventions
 of ``repro.models.attention``.
 
-Quantized (int8) pools pass their per-(page, offset, kv-head) bf16 scale
-leaves as optional ``k_scale``/``v_scale`` operands ([P, ps, KV]; None =
-fp pages).  Kernel and ref apply the *identical* fused math — raw int8
+Per-head pages are head-major [P, KV, ps, hd] (the layout whose page
+blocks the TPU can tile; see ``kernel.py``).  Quantized (int8) pools pass
+their per-(page, kv-head, offset) bf16 scale leaves as optional
+``k_scale``/``v_scale`` operands ([P, KV, 1, ps]; None = fp pages).  Kernel and ref apply the *identical* fused math — raw int8
 scores scaled per key column, probabilities scaled per value row before
 the PV product — so kernel-on vs kernel-off stays token-identical for
 quantized layouts too.  MLA latent pages never quantize (the layout seam
@@ -33,7 +34,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.paged_attention.kernel import (paged_attention_kernel,
+from repro.kernels.paged_attention.kernel import (check_page_size,
+                                                  paged_attention_kernel,
                                                   paged_mla_kernel,
                                                   paged_mla_prefill_kernel,
                                                   paged_prefill_kernel,
@@ -51,6 +53,17 @@ def _interp(interpret: bool) -> bool:
     return interpret or jax.default_backend() != "tpu"
 
 
+def _kernel_interp(interpret: bool, pages) -> bool:
+    """``_interp`` for a kernel over ``pages`` ([P, KV, ps, hd] or latent
+    [P, ps, R]); a compiled (non-interpreted) kernel first checks that the
+    page size tiles the TPU's sublanes, so a bad ``page_size`` fails with
+    its name instead of a Mosaic block-shape error."""
+    interp = _interp(interpret)
+    if not interp:
+        check_page_size(pages.shape[-2], pages.dtype)
+    return interp
+
+
 def _meta(start, n_valid):
     return jnp.stack([jnp.asarray(start, jnp.int32),
                       jnp.asarray(n_valid, jnp.int32)])
@@ -61,14 +74,14 @@ def _meta(start, n_valid):
 def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
                     window: int = 0, k_scale=None, v_scale=None,
                     use_kernel: bool = False, interpret: bool = False):
-    """q: [slots, H, hd]; k/v_pages: [P, ps, KV, hd]; page_table:
+    """q: [slots, H, hd]; k/v_pages: [P, KV, ps, hd]; page_table:
     [slots, n_table] int32 (pad with 0, the trash page); lengths: [slots]
     int32 (valid tokens per slot).  ``window > 0`` selects the ring-cell
     position mapping (sliding-window mask included).  ``k_scale``/
-    ``v_scale`` [P, ps, KV] bf16 mark int8 pages (dequant fused into the
+    ``v_scale`` [P, KV, 1, ps] bf16 mark int8 pages (dequant fused into the
     softmax accumulation).  Returns [slots, H, hd] in q.dtype."""
     slots, H, hd = q.shape
-    KV = k_pages.shape[2]
+    KV = k_pages.shape[1]
     if not use_kernel:
         return paged_attention_ref(q, k_pages, v_pages, page_table, lengths,
                                    window=window, k_scale=k_scale,
@@ -78,7 +91,7 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
                                  v_pages, page_table, lengths,
                                  window=window, k_scale=k_scale,
                                  v_scale=v_scale,
-                                 interpret=_interp(interpret))
+                                 interpret=_kernel_interp(interpret, k_pages))
     return out.reshape(slots, H, hd)
 
 
@@ -98,7 +111,7 @@ def paged_mla_attention(q_lat, q_rope, ckv_pages, krope_pages, page_table,
                                        scale=scale)
     return paged_mla_kernel(q_lat, q_rope, ckv_pages, krope_pages,
                             page_table, lengths, scale=scale,
-                            interpret=_interp(interpret))
+                            interpret=_kernel_interp(interpret, ckv_pages))
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
@@ -107,21 +120,21 @@ def paged_prefill(q, k_pages, v_pages, page_table, start, n_valid, *,
                   interpret: bool = False):
     """Contiguous-layout chunked prefill.  q: [S, H, hd] — one request's
     bucketed chunk (post-rope; query i holds absolute position
-    ``start + i``); k/v_pages: [P, ps, KV, hd] — the pool AFTER the
+    ``start + i``); k/v_pages: [P, KV, ps, hd] — the pool AFTER the
     chunk's K/V were scattered in; page_table: [n] int32 — the request's
     row (0-padded tail = trash); start / n_valid traced scalars.  Rows
     past ``n_valid`` are bucket padding — their output is undefined and
-    must not be read.  ``k_scale``/``v_scale`` [P, ps, KV] bf16 mark int8
-    pages.  Returns [S, H, hd] in q.dtype."""
+    must not be read.  ``k_scale``/``v_scale`` [P, KV, 1, ps] bf16 mark
+    int8 pages.  Returns [S, H, hd] in q.dtype."""
     S, H, hd = q.shape
     if not use_kernel:
         return paged_prefill_ref(q, k_pages, v_pages, page_table, start,
                                  n_valid, k_scale=k_scale, v_scale=v_scale)
-    KV = k_pages.shape[2]
+    KV = k_pages.shape[1]
     out = paged_prefill_kernel(q.reshape(S, KV, H // KV, hd), k_pages,
                                v_pages, page_table, _meta(start, n_valid),
                                k_scale=k_scale, v_scale=v_scale,
-                               interpret=_interp(interpret))
+                               interpret=_kernel_interp(interpret, k_pages))
     return out.reshape(S, H, hd)
 
 
@@ -136,7 +149,7 @@ def paged_ring_prefill(q, k_pages, v_pages, chunk_k, chunk_v, page_table,
     chunk's writes and chunk_k/chunk_v [S, KV, hd] are the chunk's own
     post-rope keys/values (its writes wrap onto ring cells its early
     queries still need, so they must not be read back through the table).
-    ``k_scale``/``v_scale`` [P, ps, KV] bf16 mark int8 *snapshot* pages —
+    ``k_scale``/``v_scale`` [P, KV, 1, ps] bf16 mark int8 *snapshot* pages —
     the chunk operands always stay fp (freshly projected, never read back
     from the pool).  Returns [S, H, hd] in q.dtype."""
     S, H, hd = q.shape
@@ -145,12 +158,13 @@ def paged_ring_prefill(q, k_pages, v_pages, chunk_k, chunk_v, page_table,
                                       chunk_v, page_table, start, n_valid,
                                       window=window, k_scale=k_scale,
                                       v_scale=v_scale)
-    KV = k_pages.shape[2]
+    KV = k_pages.shape[1]
     out = paged_ring_prefill_kernel(q.reshape(S, KV, H // KV, hd), k_pages,
                                     v_pages, chunk_k, chunk_v, page_table,
                                     _meta(start, n_valid), window=window,
                                     k_scale=k_scale, v_scale=v_scale,
-                                    interpret=_interp(interpret))
+                                    interpret=_kernel_interp(interpret,
+                                                             k_pages))
     return out.reshape(S, H, hd)
 
 
@@ -172,4 +186,5 @@ def paged_mla_prefill(q_lat, q_rope, ckv_pages, krope_pages, page_table,
     return paged_mla_prefill_kernel(q_lat, q_rope, ckv_pages, krope_pages,
                                     page_table, _meta(start, n_valid),
                                     scale=scale,
-                                    interpret=_interp(interpret))
+                                    interpret=_kernel_interp(interpret,
+                                                             ckv_pages))

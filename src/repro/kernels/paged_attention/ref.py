@@ -2,9 +2,10 @@
 
 Layouts (the serving pool's native shapes, one per ``KVLayout``):
   * ``q``           [slots, H, hd]        — one query token per decode slot
-  * ``k/v_pages``   [P, ps, KV, hd]       — per-head page pool ("kv" and
-                                            ring "window" layouts; page 0
-                                            is the reserved trash page)
+  * ``k/v_pages``   [P, KV, ps, hd]       — head-major per-head page pool
+                                            ("kv" and ring "window"
+                                            layouts; page 0 is the reserved
+                                            trash page)
   * ``ckv/krope_pages`` [P, ps, R] / [P, ps, rp] — latent page pool (MLA)
   * ``page_table``  [slots, n] int32      — per-slot page ids; entries past
                                             a slot's held pages point at
@@ -26,8 +27,9 @@ GQA head convention matches ``repro.models.attention``: head h = kv-head
 [slots, n*ps] score matrix — correctness only; the Pallas kernels only
 ever touch pages a slot actually holds.
 
-Quantized (int8) pools pass ``k_scale``/``v_scale`` [P, ps, KV] bf16 (one
-symmetric scale per (page, offset, kv-head) row, widened to fp32 on read).  The oracles mirror the
+Quantized (int8) pools pass ``k_scale``/``v_scale`` [P, KV, 1, ps] bf16
+(one symmetric scale per (page, kv-head, offset) row, widened to fp32 on
+read).  The oracles mirror the
 kernels' *fused* dequant exactly — raw int8 scores are computed first and
 multiplied by the key's scale per column, probabilities are multiplied by
 the value's scale per row before the PV product; fp pages are never
@@ -41,6 +43,19 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.common import NEG_INF
+
+
+def _gather(pages, ids):
+    """Token-major rows [..., n*ps, KV, hd] of head-major pages
+    [P, KV, ps, hd] at page ids [..., n]."""
+    x = jnp.swapaxes(pages[ids], -3, -2)                  # [..., n, ps, KV, hd]
+    return x.reshape(*ids.shape[:-1], -1, *x.shape[-2:])
+
+
+def _gather_scales(scales, ids):
+    """fp32 per-row scales [..., n*ps, KV] of [P, KV, 1, ps] scale pages."""
+    x = jnp.swapaxes(scales[ids][..., 0, :], -2, -1)      # [..., n, ps, KV]
+    return x.reshape(*ids.shape[:-1], -1, x.shape[-1]).astype(jnp.float32)
 
 
 def ring_positions(lengths, n_tokens: int, window: int):
@@ -57,15 +72,15 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, lengths, *,
                         window: int = 0, k_scale=None, v_scale=None):
     """Returns [slots, H, hd] in q.dtype.  ``window > 0`` selects the ring
     layout's position mapping (sliding-window mask included).  ``k_scale``/
-    ``v_scale`` [P, ps, KV] mark int8 pages — fused dequant, mirroring the
-    kernel (see module docstring)."""
+    ``v_scale`` [P, KV, 1, ps] mark int8 pages — fused dequant, mirroring
+    the kernel (see module docstring)."""
     S, H, hd = q.shape
-    _, ps, KV, _ = k_pages.shape
+    _, KV, ps, _ = k_pages.shape
     n = page_table.shape[1]
     G = H // KV
     scale = hd ** -0.5
-    k = k_pages[page_table].reshape(S, n * ps, KV, hd)     # gather-all
-    v = v_pages[page_table].reshape(S, n * ps, KV, hd)
+    k = _gather(k_pages, page_table)                       # gather-all
+    v = _gather(v_pages, page_table)
     if window:
         _, valid = ring_positions(lengths, n * ps, window)
     else:
@@ -75,14 +90,12 @@ def paged_attention_ref(q, k_pages, v_pages, page_table, lengths, *,
                    k.astype(jnp.float32),
                    preferred_element_type=jnp.float32) * scale
     if k_scale is not None:
-        ks = k_scale[page_table].astype(jnp.float32) \
-                                .reshape(S, n * ps, KV)    # [S, t, KV]
+        ks = _gather_scales(k_scale, page_table)           # [S, t, KV]
         s = s * ks.transpose(0, 2, 1)[:, :, None, :]       # per key column
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     if v_scale is not None:
-        vs = v_scale[page_table].astype(jnp.float32) \
-                                .reshape(S, n * ps, KV)
+        vs = _gather_scales(v_scale, page_table)
         p = p * vs.transpose(0, 2, 1)[:, :, None, :]       # per value row
     out = jnp.einsum("skgt,stkh->skgh", p, v.astype(jnp.float32),
                      preferred_element_type=jnp.float32)
@@ -154,16 +167,16 @@ def paged_prefill_ref(q, k_pages, v_pages, page_table, start, n_valid, *,
     """Contiguous-layout chunked prefill: the pages already hold the
     chunk's K/V (positions start..start+n_valid-1), so queries attend the
     gathered pages under the written bound AND the causal horizon.
-    ``k_scale``/``v_scale`` [P, ps, KV] mark int8 pages (fused dequant).
+    ``k_scale``/``v_scale`` [P, KV, 1, ps] mark int8 pages (fused dequant).
     Returns [S, H, hd] in q.dtype."""
     S, H, hd = q.shape
-    _, ps, KV, _ = k_pages.shape
+    _, KV, ps, _ = k_pages.shape
     n = page_table.shape[0]
-    k = k_pages[page_table].reshape(n * ps, KV, hd)
-    v = v_pages[page_table].reshape(n * ps, KV, hd)
-    ks = (k_scale[page_table].astype(jnp.float32).reshape(n * ps, KV)
+    k = _gather(k_pages, page_table)
+    v = _gather(v_pages, page_table)
+    ks = (_gather_scales(k_scale, page_table)
           if k_scale is not None else None)
-    vs = (v_scale[page_table].astype(jnp.float32).reshape(n * ps, KV)
+    vs = (_gather_scales(v_scale, page_table)
           if v_scale is not None else None)
     kidx = jnp.arange(n * ps)
     qpos = start + jnp.arange(S)
@@ -183,14 +196,14 @@ def paged_ring_prefill_ref(q, k_pages, v_pages, chunk_k, chunk_v,
     values ride along as [S, KV, hd] operands.  Key positions follow the
     ring formula for the snapshot and ``start + j`` for the chunk; the
     sliding-window mask excludes every wrapped-over snapshot cell.
-    ``k_scale``/``v_scale`` [P, ps, KV] mark int8 *snapshot* pages — the
+    ``k_scale``/``v_scale`` [P, KV, 1, ps] mark int8 *snapshot* pages — the
     chunk operands stay fp, so their fused scale is 1.
     Returns [S, H, hd] in q.dtype."""
     S, H, hd = q.shape
-    _, ps, KV, _ = k_pages.shape
+    _, KV, ps, _ = k_pages.shape
     n = page_table.shape[0]
-    ring_k = k_pages[page_table].reshape(n * ps, KV, hd)
-    ring_v = v_pages[page_table].reshape(n * ps, KV, hd)
+    ring_k = _gather(k_pages, page_table)
+    ring_v = _gather(v_pages, page_table)
     cur = start - 1
     i = jnp.arange(n * ps)
     ring_pos = cur - jnp.mod(cur - i, window)       # < 0 = never written
@@ -201,12 +214,10 @@ def paged_ring_prefill_ref(q, k_pages, v_pages, chunk_k, chunk_v,
         vv = jnp.concatenate([ring_v.astype(jnp.float32),
                               chunk_v.astype(jnp.float32)], axis=0)
         ones = jnp.ones((S, KV), jnp.float32)
-        ks = jnp.concatenate(
-            [k_scale[page_table].astype(jnp.float32).reshape(n * ps, KV),
-             ones], axis=0)
-        vs = jnp.concatenate(
-            [v_scale[page_table].astype(jnp.float32).reshape(n * ps, KV),
-             ones], axis=0)
+        ks = jnp.concatenate([_gather_scales(k_scale, page_table), ones],
+                             axis=0)
+        vs = jnp.concatenate([_gather_scales(v_scale, page_table), ones],
+                             axis=0)
     else:
         kk = jnp.concatenate([ring_k, chunk_k.astype(ring_k.dtype)], axis=0)
         vv = jnp.concatenate([ring_v, chunk_v.astype(ring_v.dtype)], axis=0)

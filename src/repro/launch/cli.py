@@ -14,9 +14,13 @@ from __future__ import annotations
 
 import argparse
 import os
+from pathlib import Path
 from typing import Optional
 
 from repro.api.session import parse_mesh  # the single --mesh parser
+
+#: the checkout's root, resolved from this file (src/repro/launch/cli.py)
+_REPO_ROOT = Path(__file__).resolve().parents[3]
 
 
 def add_session_flags(ap: argparse.ArgumentParser, *,
@@ -59,10 +63,25 @@ def bootstrap_devices(args):
             f"{flags} --xla_force_host_platform_device_count={n}".strip())
 
 
+def enable_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for this process.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    this sets nothing.  Otherwise the cache lives at ``<repo>/.jax_cache``:
+    a fixed path, so a later run of the same checkout finds its entries.
+    """
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    jax.config.update("jax_compilation_cache_dir",
+                      str(_REPO_ROOT / ".jax_cache"))
+
+
 def make_session(args, **load_kw):
     """Build the Session a driver runs against (import deferred past
     ``bootstrap_devices`` on purpose)."""
     bootstrap_devices(args)
+    enable_compile_cache()
     from repro import api
     return api.load(args.arch, smoke=args.smoke, mesh=args.mesh,
                     seed=args.seed, **load_kw)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell on the
 production mesh and record memory / cost / collective analyses.
 
@@ -12,8 +9,11 @@ cost.  User-facing train/serve entrypoints live behind ``repro.api`` and
 ``launch/cli.py``; the ``--mesh`` flag here selects the *production* preset
 (single: 16x16, multi: 2x16x16), not the free-form ``DxM`` spec.
 
-The two lines above MUST stay first: jax locks the device count on first
-initialization, and the production meshes need 512 placeholder CPU devices.
+The environment lines below MUST run before jax is imported: jax locks the
+device count on first initialization, and the production meshes need 512
+placeholder CPU devices.  The backend is pinned to the CPU because those
+placeholders are CPU devices — on a machine with a TPU, jax would otherwise
+pick the TPU, see its few chips, and hold them for this whole process.
 Smoke tests and benchmarks do NOT import this module (they see 1 device).
 
 Usage:
@@ -22,11 +22,14 @@ Usage:
   python -m repro.launch.dryrun --list           # enumerate cells
 
 One JSON per cell lands in results/dryrun/; existing files are skipped
-(incremental).  Run cells in subprocesses via --all to isolate compile memory.
+(incremental).
 """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 import argparse
 import json
-import subprocess
 import sys
 import time
 import traceback
@@ -199,9 +202,8 @@ def analyze_cell(arch: str, shape_name: str, mesh_name: str, mode: str,
     compiled = lowered.compile()
     t_compile = time.time() - t0
 
-    from repro.core.compat import cost_analysis
     ma = compiled.memory_analysis()
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     hlo = compiled.as_text()
     stats = analyze_module(hlo)
     shape = get_shape(shape_name)
@@ -283,8 +285,6 @@ def main():
     ap.add_argument("--list", action="store_true")
     ap.add_argument("--force", action="store_true")
     ap.add_argument("--save-hlo", action="store_true")
-    ap.add_argument("--subprocess", action="store_true",
-                    help="with --all: isolate each cell in a subprocess")
     # hillclimb knobs (single-cell runs)
     ap.add_argument("--tag", default="", help="suffix for the result file")
     ap.add_argument("--microbatch", type=int, default=None)
@@ -332,16 +332,7 @@ def main():
                 if prev.get("ok"):
                     n_skip += 1
                     continue
-            if args.subprocess:
-                r = subprocess.run(
-                    [sys.executable, "-m", "repro.launch.dryrun",
-                     "--arch", arch, "--shape", shape, "--mesh", mesh,
-                     "--mode", mode, "--force"]
-                    + (["--save-hlo"] if args.save_hlo else []),
-                    env={**os.environ, "PYTHONPATH": str(Path(__file__).resolve().parents[2])})
-                ok = r.returncode == 0 and json.loads(out.read_text()).get("ok", False)
-            else:
-                ok = run_one(arch, shape, mesh, mode, args.save_hlo)
+            ok = run_one(arch, shape, mesh, mode, args.save_hlo)
             n_ok += int(ok)
             n_err += int(not ok)
         print(f"done: ok={n_ok} err={n_err} skipped={n_skip}")

@@ -7,6 +7,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 from repro.configs.base import MeshConfig, MULTI_POD, SINGLE_POD
 
@@ -18,12 +19,19 @@ def make_production_mesh(*, multi_pod: bool = False):
     *before* any jax import so this can build on CPU."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def build_mesh(cfg: MeshConfig):
     """Mesh from an arbitrary MeshConfig (tests use small shapes)."""
-    return jax.make_mesh(tuple(cfg.shape), tuple(cfg.axis_names))
+    return auto_mesh(tuple(cfg.shape), tuple(cfg.axis_names))
+
+
+def auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with every axis Auto: the model code places arrays
+    through GSPMD sharding constraints, which Explicit axes (the default
+    since jax 0.7) reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_config_for(mesh) -> MeshConfig:

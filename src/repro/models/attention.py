@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from repro.models.common import ParamSpec, apply_rope, dot
 # the single quantizer for int8 KV pages (layouts depends only on jax, so
 # this does not cross the serving layer's import boundary)
-from repro.serving.layouts import SCALE_SUFFIX, quantize_kv
+from repro.serving.layouts import SCALE_SUFFIX, gather_rows, write_rows
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -309,8 +309,9 @@ def _paged_window(cfg) -> int:
 def paged_cache_update(kv, k_new, v_new, page_table, pos, *, window: int = 0):
     """Write one decode step's K/V into the shared page pool.
 
-    kv: {"k","v"}: [P, ps, KV, hd] (one layer's pages); k_new/v_new
-    [slots, 1, KV, hd]; page_table [slots, n] int32; pos [slots] int32 —
+    kv: {"k","v"}: [P, KV, ps, hd] (one layer's head-major pages);
+    k_new/v_new [slots, 1, KV, hd]; page_table [slots, n] int32; pos
+    [slots] int32 —
     token t of slot s lands in page ``page_table[s, t // ps]`` at offset
     ``t % ps`` (contiguous), or — ring layout, ``window > 0`` — in cell
     ``(t % window) // ps`` of the slot's ring table, same in-page offset
@@ -319,25 +320,15 @@ def paged_cache_update(kv, k_new, v_new, page_table, pos, *, window: int = 0):
     trash page.
 
     Quantized (int8) pools carry a ``*_scale`` leaf per data leaf; the row
-    is quantized once here (``quantize_kv``) and both the int8 row and its
-    per-head scale scatter to the same (page, offset).
+    is quantized once (``layouts.write_rows``) and both the int8 row and
+    its per-head scale scatter to the same (page, offset).
     """
-    ps = kv["k"].shape[1]
+    ps = kv["k"].shape[2]
     idx = pos % window if window else pos
     page = jnp.take_along_axis(page_table, (idx // ps)[:, None], axis=1)[:, 0]
     off = pos % ps
-    out = {}
-    for name, new in (("k", k_new), ("v", v_new)):
-        row = new[:, 0]
-        if name + SCALE_SUFFIX in kv:
-            qrow, srow = quantize_kv(row)
-            out[name] = kv[name].at[page, off].set(qrow)
-            out[name + SCALE_SUFFIX] = \
-                kv[name + SCALE_SUFFIX].at[page, off].set(srow)
-        else:
-            out[name] = kv[name].at[page, off].set(
-                row.astype(kv[name].dtype))
-    return out
+    return {**write_rows(kv, "k", page, off, k_new[:, 0]),
+            **write_rows(kv, "v", page, off, v_new[:, 0])}
 
 
 def paged_latent_update(kv, ckv_new, krope_new, page_table, pos):
@@ -373,28 +364,19 @@ def paged_prefill_write(kv, k_new, v_new, page_ids, start, n_valid, *,
                         window: int = 0):
     """Write one prefill chunk's K/V into the shared page pool.
 
-    kv: {"k","v"}: [P, ps, KV, hd] (one layer's pages); k_new/v_new
-    [1, S, KV, hd] (S = padded bucket length); page_ids [n] int32 — one
+    kv: {"k","v"}: [P, KV, ps, hd] (one layer's head-major pages);
+    k_new/v_new [1, S, KV, hd] (S = padded bucket length); page_ids [n]
+    int32 — one
     request's page-table row; start / n_valid traced scalars.  Position
     mapping per ``_chunk_targets`` (contiguous or ring).  Quantized pools
     scatter int8 rows + per-head scales (see ``paged_cache_update``);
     padding rows land harmlessly in the trash page, scales included.
     """
-    ps = kv["k"].shape[1]
+    ps = kv["k"].shape[2]
     page, off = _chunk_targets(page_ids, start, n_valid, k_new.shape[1], ps,
                                window)
-    out = {}
-    for name, new in (("k", k_new), ("v", v_new)):
-        rows = new[0]
-        if name + SCALE_SUFFIX in kv:
-            qrows, srows = quantize_kv(rows)
-            out[name] = kv[name].at[page, off].set(qrows)
-            out[name + SCALE_SUFFIX] = \
-                kv[name + SCALE_SUFFIX].at[page, off].set(srows)
-        else:
-            out[name] = kv[name].at[page, off].set(
-                rows.astype(kv[name].dtype))
-    return out
+    return {**write_rows(kv, "k", page, off, k_new[0]),
+            **write_rows(kv, "v", page, off, v_new[0])}
 
 
 def paged_latent_prefill_write(kv, ckv_new, krope_new, page_ids, start,
@@ -448,8 +430,6 @@ def paged_prefill_apply(cfg, p, x, positions, kv, page_ids, start, n_valid,
     hd = cfg.resolved_head_dim
     H = cfg.num_heads
     cd = x.dtype
-    ps = kv["k"].shape[1]
-    n = page_ids.shape[0]
     window = _paged_window(cfg)
     quantized = "k" + SCALE_SUFFIX in kv
 
@@ -468,10 +448,10 @@ def paged_prefill_apply(cfg, p, x, positions, kv, page_ids, start, n_valid,
                 v_scale=kv.get("v" + SCALE_SUFFIX),
                 use_kernel=use_pallas)[None]
         else:
-            ring_k = kv["k"][page_ids].reshape(1, n * ps, *k.shape[2:])
-            ring_v = kv["v"][page_ids].reshape(1, n * ps, *v.shape[2:])
+            ring_k = gather_rows(kv["k"], page_ids)[None]
+            ring_v = gather_rows(kv["v"], page_ids)[None]
             cur = start - 1
-            i = jnp.arange(n * ps)
+            i = jnp.arange(ring_k.shape[1])
             ring_pos = cur - jnp.mod(cur - i, window)  # < 0 = never written
             kk = jnp.concatenate([ring_k.astype(cd), k], axis=1)
             vv = jnp.concatenate([ring_v.astype(cd), v], axis=1)
@@ -498,9 +478,9 @@ def paged_prefill_apply(cfg, p, x, positions, kv, page_ids, start, n_valid,
             # absolute key positions are the identity, validity =
             # written-so-far bound (trash entries in the table tail sit
             # past the bound, so they are never seen)
-            kk = new_kv["k"][page_ids].reshape(1, n * ps, *k.shape[2:])
-            vv = new_kv["v"][page_ids].reshape(1, n * ps, *v.shape[2:])
-            k_pos = jnp.arange(n * ps)
+            kk = gather_rows(new_kv["k"], page_ids)[None]
+            vv = gather_rows(new_kv["v"], page_ids)[None]
+            k_pos = jnp.arange(kk.shape[1])
             kv_valid = (k_pos < start + n_valid)[None, :]
             out = attention_core(q, kk.astype(cd), vv.astype(cd), positions,
                                  k_pos, causal=True,

@@ -209,28 +209,20 @@ def activation_batch_axes(axes):
 def maybe_wsc(x, spec: P):
     """with_sharding_constraint that no-ops outside a mesh context or when the
     referenced axes are absent/manual (smoke tests run on 1 CPU device)."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty:
-            return x
-        parts = list(spec)
-        if (_ACT_BATCH_AXES and parts and parts[0] is None
-                and x.ndim == len(parts) and x.shape[0] > 1):
-            parts[0] = _ACT_BATCH_AXES
-            spec = P(*parts)
-        axes = set()
-        for part in spec:
-            if part is None:
-                continue
-            axes.update(part if isinstance(part, tuple) else (part,))
-        for a in axes:
-            if a not in mesh.axis_names:
-                return x
-            if mesh._name_to_type[a] != jax.sharding.AxisType.Auto:
-                return x
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
+    parts = list(spec)
+    if (_ACT_BATCH_AXES and parts and parts[0] is None
+            and x.ndim == len(parts) and x.shape[0] > 1):
+        parts[0] = _ACT_BATCH_AXES
+        spec = P(*parts)
+    axis_types = dict(zip(mesh.axis_names, mesh.axis_types))
+    for part in spec:
+        for a in (part if isinstance(part, tuple) else (part,)):
+            if a is not None and axis_types.get(a) != jax.sharding.AxisType.Auto:
+                return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def cast_tree(tree, dtype):

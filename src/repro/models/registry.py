@@ -71,7 +71,7 @@ class ServeContract(Protocol):
 class PagedServeContract(Protocol):
     """Paged batched decode against the shared page pool:
     ``(params, tokens, state, *, use_pallas=False) -> (logits [slots, V],
-    pages)`` with ``state = {"pages": {leaf: [L, P, ps, ...]},
+    pages)`` with ``state = {"pages": {leaf: [L, P, ...]},
     "page_table": [slots, n] int32, "pos": [slots] int32}``.
 
     The page leaves are the family's ``KVLayout`` (per-head "k"/"v" for
@@ -89,7 +89,7 @@ class PagedServeContract(Protocol):
 class PagedPrefillContract(Protocol):
     """Chunked prefill straight into the page pool (the prefix-cache path):
     ``(params, tokens, state, *, use_pallas=False) -> (logits [1, V],
-    pages)`` with ``state = {"pages": {"k","v"}: [L, P, ps, KV, hd],
+    pages)`` with ``state = {"pages": {"k","v"}: [L, P, KV, ps, hd],
     "page_table": [n] int32, "start": scalar, "n_valid": scalar}``.
 
     ``tokens`` [1, S] is one request's uncached suffix chunk padded to a

@@ -297,7 +297,7 @@ def lm_paged_prefill(cfg, params, tokens, state, *, use_pallas: bool = False):
     (forward body shared with ``lm_paged_verify``).
 
     tokens [1, S] — S is a padded power-of-two bucket; state:
-      * ``pages``      {"k","v"}: [L, P, ps, KV, hd] — global page pool
+      * ``pages``      {"k","v"}: [L, P, KV, ps, hd] — global page pool
       * ``page_table`` [n] int32 — this request's page-table row
       * ``start``      traced scalar — tokens already cached (shared prefix
         pages + earlier chunks); the chunk holds positions start..start+S-1
@@ -436,7 +436,7 @@ def lm_paged_decode(cfg, params, tokens, state, *, use_pallas: bool = False):
     Unlike ``lm_decode`` (one private ring cache per sequence, vmapped by
     the engine), the pool is shared, so the whole slot batch runs as one
     call.  tokens [slots, 1]; state:
-      * ``pages``      {"k","v"}: [L, P, ps, KV, hd] — global page pool
+      * ``pages``      {"k","v"}: [L, P, KV, ps, hd] — global page pool
       * ``page_table`` [slots, n] int32 — per-slot page ids (0 = trash)
       * ``pos``        [slots] int32 — tokens already cached per slot
         (= the position this step's token is written at)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Dry-run profiler: lower one cell and print the top FLOP / HBM-byte /
 collective contributors with op metadata — the 'profile' used by the §Perf
 hypothesis->change->measure loop (no real hardware; the lowered IR is the
@@ -8,7 +5,16 @@ profile, per the Pallas dry-run methodology).
 
   PYTHONPATH=src python -m repro.roofline.profile_cell \
       --arch qwen2.5-14b --shape train_4k --mesh single --mode fsdp
+
+Like ``repro.launch.dryrun``, this lowers for 512 placeholder CPU devices,
+so it pins jax to the CPU before jax is imported: on a machine with a TPU
+the default backend would be the TPU, which has neither the devices nor
+the intent of this tool.
 """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 import argparse
 
 from repro.launch.dryrun import lower_cell

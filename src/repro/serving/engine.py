@@ -412,15 +412,25 @@ class ServingEngine:
         spec_width = self.cfg.spec_tokens + 1   # last token + drafts
 
         # backend-selected like core/allreduce: the Pallas paged-attention
-        # kernels on TPU (HBM traffic ~ pages held), traced ref gather on
-        # CPU.  ServeConfig.use_pallas overrides (off-TPU the kernels run
-        # in interpret mode — the ops wrappers select it automatically), so
-        # tests/CI exercise the kernel paths everywhere.  Applies to every
-        # paged dispatch: decode scans, prefill chunks, and spec-verify.
+        # kernels on one TPU device (HBM traffic ~ pages held), traced ref
+        # gather on CPU.  ServeConfig.use_pallas overrides (off-TPU the
+        # kernels run in interpret mode — the ops wrappers select it
+        # automatically), so tests/CI exercise the kernel paths everywhere.
+        # Applies to every paged dispatch: decode scans, prefill chunks, and
+        # spec-verify.  A compiled Mosaic kernel cannot be partitioned by
+        # GSPMD (it would need a shard_map around each call), so a mesh of
+        # several TPU devices serves through the gather path.
+        on_tpu = jax.default_backend() == "tpu"
+        n_devices = 1 if self.mesh is None else self.mesh.size
         if self.cfg.use_pallas is None:
-            paged_kernel = jax.default_backend() == "tpu"
+            paged_kernel = on_tpu and n_devices == 1
         else:
             paged_kernel = self.cfg.use_pallas
+            if paged_kernel and on_tpu and n_devices > 1 and self.paged:
+                raise ValueError(
+                    f"use_pallas=True on a {n_devices}-device TPU mesh: the "
+                    "compiled paged kernels run on one device only — "
+                    "leave use_pallas=None (auto) or serve on one device")
         self.paged_kernel = paged_kernel and self.paged
 
         # One fused dispatch per cycle: lax.scan over decode_steps.  Each
@@ -1094,9 +1104,11 @@ class ServingEngine:
             with tr.span("prefill.chunk", track=rt, chunk=c.chunk,
                          bucket=width, start=c.start):
                 with tr.span("prefill.device", tokens=c.chunk, bucket=width):
+                    # the table row uploads as a copy (jnp.array, see
+                    # PagedKVCachePool.insert_state)
                     logits, self.pool.pages = self._paged_prefill(
                         self.params, jnp.asarray(ctoks), self.pool.pages,
-                        jnp.asarray(self.pool.tables[c.slot]),
+                        jnp.array(self.pool.tables[c.slot]),
                         jnp.asarray(c.start, jnp.int32),
                         jnp.asarray(c.chunk, jnp.int32))
                     self._fence(logits)

@@ -23,13 +23,21 @@ family's decode cache needs:
                   growth and prefix sharing;
   * ``kv_dtype`` — the *storage* dtype of the data leaves: ``"fp32"`` keeps
                   the family's native compute dtype; ``"int8"`` stores each
-                  k/v row as int8 with a per-(page, offset, kv-head)
+                  k/v row as int8 with a per-(page, kv-head, offset)
                   symmetric bfloat16 scale carried as an extra ``*_scale``
                   leaf
                   (``quantize_kv`` is the single quantizer — write paths
                   call it; the paged-attention kernels and their jnp
                   oracles multiply the scales back into the online-softmax
                   accumulation, never materializing fp pages).
+
+Per-head pages are stored *head-major*: a k/v leaf is [L, P, KV, ps, hd]
+and a scale leaf [L, P, KV, 1, ps], so one (page, kv-head) block is a
+``(ps, hd)`` tile — the block shape the TPU's Pallas kernels can DMA
+(``kernels.paged_attention.kernel``).  The bundle's native decode state is
+token-major ([..., T, KV, hd]); ``KVLayout.to_pages``, ``write_rows`` and
+``gather_rows`` are the only conversions between the two.  Latent pages
+keep the native [L, P, ps, R] layout.
 
 ``layout_for(cfg)`` is the single capability authority: the registry asks
 it (instead of probing ``attn_kind`` strings) whether a family pages, and
@@ -163,19 +171,27 @@ class KVLayout:
         prefill caches); scale leaves exist only in the page pool."""
         return tuple(n for n in self.leaves if not n.endswith(SCALE_SUFFIX))
 
+    def to_pages(self, rows: dict) -> dict:
+        """Page storage of token-major data leaves [..., ps, ...] (whole
+        pages of the bundle's native state).  Per-head leaves
+        [..., ps, KV, hd] become head-major [..., KV, ps, hd]; quantized
+        layouts store them int8 beside a [..., KV, 1, ps] bf16 scale leaf
+        (``quantize_kv``).  Latent leaves store as given."""
+        if self.name == "latent":
+            return dict(rows)
+        out = {}
+        for name, x in rows.items():
+            if self.quantized:
+                x, scale = quantize_kv(x)
+                out[name + SCALE_SUFFIX] = jnp.swapaxes(scale, -2, -1)[
+                    ..., None, :]
+            out[name] = jnp.swapaxes(x, -3, -2)
+        return out
+
     def page_template(self, blank: dict) -> dict:
-        """One-page pool template from the bundle's native blank state:
-        identity for fp layouts; quantized layouts store each data leaf as
-        int8 and add a per-row bf16 scale leaf (the data leaf's shape with
-        head_dim dropped — one scale per (page, offset, kv-head))."""
-        if not self.quantized:
-            return {k: blank[k] for k in self.leaves}
-        one = {}
-        for name in self.data_leaves:
-            x = blank[name]
-            one[name] = jnp.zeros(x.shape, jnp.int8)
-            one[name + SCALE_SUFFIX] = jnp.zeros(x.shape[:-1], jnp.bfloat16)
-        return one
+        """One-page pool template from the bundle's native blank state
+        ([L, 1, ps, ...] data leaves) in page storage (``to_pages``)."""
+        return self.to_pages({k: blank[k] for k in self.data_leaves})
 
     def check_page_size(self, page_size: int) -> None:
         """Ring layouts need pages that tile the window (see
@@ -259,19 +275,41 @@ class KVLayout:
         pages live anywhere."""
         spec = [None] * leaf.ndim
         if model_size > 1 and name in ("k", "v") and leaf.ndim == 5:
-            if leaf.shape[3] % model_size == 0:           # [L,P,ps,KV,hd]
-                spec[3] = "model"
+            if leaf.shape[2] % model_size == 0:           # [L,P,KV,ps,hd]
+                spec[2] = "model"
             elif leaf.shape[4] % model_size == 0:
                 spec[4] = "model"
         if model_size > 1 and name in ("k_scale", "v_scale") \
-                and leaf.ndim == 4:
-            # [L,P,ps,KV] — shard KV exactly when the int8 data leaf does
+                and leaf.ndim == 5:
+            # [L,P,KV,1,ps] — shard KV exactly when the int8 data leaf does
             # (same divisibility test on the same axis); when the data leaf
             # fell back to head_dim sharding the scales replicate, which is
             # consistent because their KV dim is then unsharded too.
-            if leaf.shape[3] % model_size == 0:
-                spec[3] = "model"
+            if leaf.shape[2] % model_size == 0:
+                spec[2] = "model"
         return P(*spec)
+
+
+def write_rows(kv, name: str, page, off, rows):
+    """Scatter token rows [N, KV, hd] of per-head leaf ``name`` into one
+    layer's head-major pages at (``page``, ``off``) [N].  A pool that
+    carries the leaf's scale quantizes the rows (``quantize_kv``) and
+    scatters int8 rows and scales to the same cells.  Returns the updated
+    leaves."""
+    sname = name + SCALE_SUFFIX
+    if sname not in kv:
+        return {name: kv[name].at[page, :, off].set(
+            rows.astype(kv[name].dtype))}
+    q, scale = quantize_kv(rows)
+    return {name: kv[name].at[page, :, off].set(q),
+            sname: kv[sname].at[page, :, 0, off].set(scale)}
+
+
+def gather_rows(pages, ids):
+    """Token-major rows [n*ps, KV, hd] of one layer's head-major per-head
+    pages [P, KV, ps, hd] at page ids [n]."""
+    x = jnp.swapaxes(pages[ids], 1, 2)                    # [n, ps, KV, hd]
+    return x.reshape(-1, *x.shape[2:])
 
 
 #: the three shipped layouts (module-level so capability checks and tests

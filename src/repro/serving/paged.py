@@ -49,7 +49,8 @@ request's window (``KVLayout.needed_start``) and count everything before
 them as cached anyway (wholly window-masked, no page needed).
 
 Device state is fixed-shape (decode compiles once):
-  * ``pages``   {leaf: [L, P, ps, ...]}  — donated through decode
+  * ``pages``   {leaf: [L, P, ...]}      — page storage per
+    ``KVLayout.to_pages``; donated through decode
   * page table  [slots, table_width] int32 — host-owned (numpy); packed
     with ``pos`` and the per-slot step budgets into ONE int32 upload per
     decode cycle (``decode_operands`` — dispatch count, not bytes, is
@@ -73,8 +74,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.obs import NULL_TRACER
-from repro.serving.layouts import (KV_FULL, KVLayout, SCALE_SUFFIX,
-                                   quantize_kv)
+from repro.serving.layouts import KV_FULL, KVLayout
 
 P_ = jax.sharding.PartitionSpec
 
@@ -121,11 +121,12 @@ class PagedKVCachePool:
 
     ``blank_page_fn()`` must return ``ModelBundle.init_decode_state(1,
     page_size)`` — the layout's leaves (e.g. "k"/"v" [L, 1, ps, KV, hd] or
-    "ckv"/"krope" [L, 1, ps, R]) are the one-page template the pool tiles
-    ``num_pages`` times.  Prefill states handed to ``insert`` must be sized
-    ``cache_len == padded_len`` (``pages_per_slot * page_size``) so they
-    scatter page-by-page; the prefix-cache path (``alloc_prefix`` + the
-    engine's paged prefill) bypasses ``insert`` and writes pages in place.
+    "ckv"/"krope" [L, 1, ps, R]), in ``KVLayout.page_template`` storage,
+    are the one-page template the pool tiles ``num_pages`` times.  Prefill
+    states handed to ``insert`` must be sized ``cache_len == padded_len``
+    (``pages_per_slot * page_size``) so they scatter page-by-page; the
+    prefix-cache path (``alloc_prefix`` + the engine's paged prefill)
+    bypasses ``insert`` and writes pages in place.
     """
 
     def __init__(self, num_slots: int, page_size: int, max_seq_len: int,
@@ -161,9 +162,9 @@ class PagedKVCachePool:
                 f"paged pool ({self.layout.name} layout) needs decode-state "
                 f"leaves {self.layout.data_leaves}; missing {missing} in "
                 + str(sorted(blank)))
-        # [L,1,ps,...]; quantized layouts swap each data leaf for an int8
-        # page + a per-row fp32 scale leaf here (the bundle's native state
-        # never carries scales — the pool owns the storage format)
+        # one page of each leaf in page storage (head-major per-head pages;
+        # quantized layouts add a scale leaf — the bundle's native state
+        # never carries scales, the pool owns the storage format)
         one = self.layout.page_template(blank)
         P = self.num_pages
 
@@ -183,8 +184,6 @@ class PagedKVCachePool:
             self.shardings = None
             out_sh = {}
 
-        quantized = self.layout.quantized
-
         def _insert(pages, one_state, ids):
             """Scatter a contiguous prefill cache into pages ``ids``.
 
@@ -192,25 +191,16 @@ class PagedKVCachePool:
             ...] (the bundle's native fp state — scale leaves exist only in
             the pool); ids [pages_per_slot] int32 — entries past the
             prompt's pages point at the trash page and receive the (blank)
-            tail chunks.  Quantized layouts quantize here with the same
+            tail chunks.  ``KVLayout.to_pages`` quantizes with the same
             ``quantize_kv`` the incremental write paths use, so an inserted
             token's page bytes match what a chunked prefill would have
             written.
             """
-            def chunked(x):
-                return x[:, 0].reshape((x.shape[0], self.pages_per_slot,
+            rows = {n: x[:, 0].reshape((x.shape[0], self.pages_per_slot,
                                         page_size) + x.shape[3:])
-            out = {}
-            for n in one_state:
-                xr = chunked(one_state[n])
-                if quantized:
-                    q, s = quantize_kv(xr)
-                    out[n] = pages[n].at[:, ids].set(q)
-                    out[n + SCALE_SUFFIX] = \
-                        pages[n + SCALE_SUFFIX].at[:, ids].set(s)
-                else:
-                    out[n] = pages[n].at[:, ids].set(xr.astype(pages[n].dtype))
-            return out
+                    for n, x in one_state.items()}
+            return {n: pages[n].at[:, ids].set(x.astype(pages[n].dtype))
+                    for n, x in self.layout.to_pages(rows).items()}
 
         def _copy(pages, dst, src):
             """Copy-on-write: duplicate page ``src`` into ``dst`` (every
@@ -743,8 +733,11 @@ class PagedKVCachePool:
         writes every table entry, so the slot must hold only private pages
         (which ``alloc_for_insert`` guarantees)."""
         one_kv = {n: one_state[n] for n in self.layout.data_leaves}
+        # jnp.array copies: on the CPU, jnp.asarray of a row view can alias
+        # the host table, which later plans mutate in place while this
+        # dispatch may still be in flight
         self.pages = self._insert(self.pages, one_kv,
-                                  jnp.asarray(self.tables[slot]))
+                                  jnp.array(self.tables[slot]))
 
     def insert(self, rid: int, one_state, n_tokens: int) -> Optional[int]:
         """One-shot admission: ``alloc_for_insert`` + ``insert_state``."""

@@ -5,9 +5,7 @@ its own flag in its own process (launch/dryrun.py); benchmarks run with the
 default single device.
 
 Also makes the suite runnable without PYTHONPATH=src (falls back to the
-src/ layout when ``repro`` isn't installed, e.g. before ``pip install -e .``)
-and aliases ``jax.shard_map`` to the version-tolerant wrapper on jax
-versions that predate it (tests exercise the new-style signature).
+src/ layout when ``repro`` isn't installed, e.g. before ``pip install -e .``).
 """
 import os
 import sys
@@ -24,19 +22,17 @@ import jax  # noqa: E402  (initialize after the flag)
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
-if not hasattr(jax, "shard_map"):
-    from repro.core.compat import shard_map as _compat_shard_map
-    jax.shard_map = _compat_shard_map
+from repro.launch.mesh import auto_mesh  # noqa: E402
 
 
 @pytest.fixture(scope="session")
 def mesh222():
-    return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    return auto_mesh((2, 2, 2), ("pod", "data", "model"))
 
 
 @pytest.fixture(scope="session")
 def mesh42():
-    return jax.make_mesh((4, 2), ("data", "model"))
+    return auto_mesh((4, 2), ("data", "model"))
 
 
 @pytest.fixture()

@@ -25,8 +25,7 @@ def test_scan_flops_exact():
     assert stats.dot_flops == pytest.approx(2 * B * D * D * L, rel=1e-6)
     assert L in stats.while_trip_counts
     # XLA's own analysis undercounts by exactly the trip count
-    from repro.core.compat import cost_analysis
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     assert ca["flops"] == pytest.approx(stats.dot_flops / L, rel=0.2)
 
 

@@ -27,8 +27,8 @@ def _paged_case(rng, slots, H, KV, hd, ps, n, dtype):
     """Random pool + disjoint per-slot page tables + random lengths."""
     P = slots * n + 1                               # page 0 = trash
     q = jnp.asarray(rng.normal(size=(slots, H, hd)), dtype)
-    kp = jnp.asarray(rng.normal(size=(P, ps, KV, hd)), dtype)
-    vp = jnp.asarray(rng.normal(size=(P, ps, KV, hd)), dtype)
+    kp = jnp.asarray(rng.normal(size=(P, KV, ps, hd)), dtype)
+    vp = jnp.asarray(rng.normal(size=(P, KV, ps, hd)), dtype)
     lengths = np.asarray(rng.integers(1, n * ps + 1, size=slots), np.int32)
     table = np.zeros((slots, n), np.int32)
     pid = 1
@@ -75,10 +75,12 @@ def test_paged_ref_matches_ring_cache_decode(rng):
     cache = {"k": k, "v": v, "pos": pos, "index": jnp.asarray(m, jnp.int32)}
     ring = decode_attend(q, cache)                   # [1, 1, H, hd]
 
-    kp = jnp.concatenate([jnp.zeros((1, ps, KV, hd)),
-                          k[0].reshape(n, ps, KV, hd)])   # page 0 = trash
-    vp = jnp.concatenate([jnp.zeros((1, ps, KV, hd)),
-                          v[0].reshape(n, ps, KV, hd)])
+    kp = jnp.concatenate([jnp.zeros((1, KV, ps, hd)),
+                          k[0].reshape(n, ps, KV, hd)
+                          .transpose(0, 2, 1, 3)])   # page 0 = trash
+    vp = jnp.concatenate([jnp.zeros((1, KV, ps, hd)),
+                          v[0].reshape(n, ps, KV, hd)
+                          .transpose(0, 2, 1, 3)])
     table = jnp.asarray([[1, 2, 3]], jnp.int32)
     paged = paged_attention(q[:, 0], kp, vp, table,
                             jnp.asarray([m], jnp.int32))
@@ -108,10 +110,12 @@ def test_paged_window_ref_matches_ring_cache_decode(rng, m):
              "index": jnp.asarray(m, jnp.int32)}
     ring = decode_attend(q, cache, window=window)
 
-    kp = jnp.concatenate([jnp.zeros((1, ps, KV, hd)),
-                          k[0].reshape(n, ps, KV, hd)])
-    vp = jnp.concatenate([jnp.zeros((1, ps, KV, hd)),
-                          v[0].reshape(n, ps, KV, hd)])
+    kp = jnp.concatenate([jnp.zeros((1, KV, ps, hd)),
+                          k[0].reshape(n, ps, KV, hd)
+                          .transpose(0, 2, 1, 3)])
+    vp = jnp.concatenate([jnp.zeros((1, KV, ps, hd)),
+                          v[0].reshape(n, ps, KV, hd)
+                          .transpose(0, 2, 1, 3)])
     table = jnp.asarray([[1, 2, 3]], jnp.int32)
     lengths = jnp.asarray([m], jnp.int32)
     for use_kernel in (False, True):
@@ -123,11 +127,12 @@ def test_paged_window_ref_matches_ring_cache_decode(rng, m):
 
 
 def _quantized_pages(kp, vp):
-    """Quantize a random fp pool the way the pool's write paths do."""
+    """Quantize a random head-major fp pool [P, KV, ps, hd] the way the
+    pool's write paths do; scales come back as [P, KV, 1, ps] pages."""
     from repro.serving.layouts import quantize_kv
     kq, ks = quantize_kv(kp)
     vq, vs = quantize_kv(vp)
-    return kq, vq, ks, vs
+    return kq, vq, ks[:, :, None, :], vs[:, :, None, :]
 
 
 @pytest.mark.parametrize("window", [0, 24], ids=["full", "ring"])
@@ -259,8 +264,8 @@ def test_trash_page_never_read(rng):
 def _prefill_case(rng, H, KV, hd, ps, n, S, dtype=jnp.float32):
     """Random page pool + the request's table row [n] (pages 1..n)."""
     q = jnp.asarray(rng.normal(size=(S, H, hd)), dtype)
-    kp = jnp.asarray(rng.normal(size=(n + 1, ps, KV, hd)), dtype)
-    vp = jnp.asarray(rng.normal(size=(n + 1, ps, KV, hd)), dtype)
+    kp = jnp.asarray(rng.normal(size=(n + 1, KV, ps, hd)), dtype)
+    vp = jnp.asarray(rng.normal(size=(n + 1, KV, ps, hd)), dtype)
     table = jnp.arange(1, n + 1, dtype=jnp.int32)
     return q, kp, vp, table
 

@@ -13,6 +13,7 @@ from repro.configs.base import (MeshConfig, OptimizerConfig, RunConfig,
 from repro.core.transparent import TransparentTrainer
 from repro.data.pipeline import device_put_global, make_input_pipeline
 from repro.data.readers import synthetic_tokens
+from repro.launch.mesh import auto_mesh
 from repro.models import registry
 
 
@@ -81,7 +82,7 @@ def test_dryrun_cell_on_test_mesh():
     (the 512-device production run is exercised by launch/dryrun.py)."""
     cfg = get_config("stablelm-1.6b", smoke=True)
     bundle = registry.build(cfg)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
     run = RunConfig(model=cfg, shape=ShapeConfig("t", "train", 16, 8),
                     mesh=MeshConfig(shape=(2, 2, 2),
                                     axis_names=("pod", "data", "model"),
@@ -91,8 +92,7 @@ def test_dryrun_cell_on_test_mesh():
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     assert ma.temp_size_in_bytes > 0
-    from repro.core.compat import cost_analysis
-    assert cost_analysis(compiled).get("flops", 0) > 0
+    assert compiled.cost_analysis().get("flops", 0) > 0
     from repro.roofline.hlo_parse import analyze_module
     stats = analyze_module(compiled.as_text())
     assert stats.dot_flops > 0
