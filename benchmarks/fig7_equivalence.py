@@ -2,10 +2,9 @@
 identical.
 
 Runs in the benchmark's own process, on the devices that process sees: the
-distributed curve is data-parallel over 4 devices (x 2-way model parallel
-when 8 are visible).  On a CPU host, give the process placeholder devices
-before it first imports jax:
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``."""
+distributed curve is pure data parallelism over 4 devices, the paper's
+Fig. 7 setting.  On a CPU host, give the process placeholder devices before
+it first imports jax: ``XLA_FLAGS=--xla_force_host_platform_device_count=4``."""
 from __future__ import annotations
 
 import jax
@@ -27,8 +26,7 @@ def _curves():
         raise RuntimeError(
             f"fig7 needs at least 4 devices for its DP-4 curve, this process "
             f"sees {n_dev}; on a CPU host set XLA_FLAGS="
-            "--xla_force_host_platform_device_count=8 before jax is imported")
-    dist_shape = (4, 2) if n_dev >= 8 else (4, 1)
+            "--xla_force_host_platform_device_count=4 before jax is imported")
     cfg = get_config("stablelm-1.6b", smoke=True)
     bundle = registry.build(cfg)
     rng = np.random.default_rng(7)
@@ -53,14 +51,13 @@ def _curves():
             out.append(float(m["loss"]))
         return out
 
-    return curve((1, 1)), curve(dist_shape, allreduce="layerwise"), dist_shape
+    return curve((1, 1)), curve((4, 1), allreduce="layerwise")
 
 
 def run():
-    seq, dist, dist_shape = _curves()
+    seq, dist = _curves()
     dev = max(abs(a - b) for a, b in zip(seq, dist))
-    print(f"# Fig7: sequential vs DP mesh {dist_shape} loss curves "
-          f"({STEPS} steps)")
+    print(f"# Fig7: sequential vs DP-4 loss curves ({STEPS} steps)")
     print("step  sequential  distributed")
     for i, (a, b) in enumerate(zip(seq, dist)):
         print(f"{i:4d}  {a:10.6f}  {b:10.6f}")

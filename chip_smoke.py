@@ -234,6 +234,9 @@ def four_chips(clock):
     same = sum(a == b for r, g in zip(ref, got) for a, b in zip(r, g))
     print(json.dumps({"serve_token_agreement": same / (PROMPTS * MAX_NEW)}),
           flush=True)
+    print(json.dumps({"peak_bytes_in_use": [
+        (d.memory_stats() or {}).get("peak_bytes_in_use")
+        for d in jax.devices()]}), flush=True)
 
 
 def main():

@@ -58,10 +58,11 @@ n_valid).
 
 Block shapes obey the TPU rule that the last two dims of a block are
 divisible by (8, 128) or equal the array's: a k/v page block is
-``(1, 1, ps, hd)``, so ``page_size`` must be a multiple of the storage
-dtype's sublane tile (``check_page_size``); prefill queries are handed to
-the kernel head-major as [KV, S*G, hd] so one block is a contiguous
-``(qb*G, hd)`` tile.
+``(1, 1, ps, hd)``, whose last two dims are the page's own, so any
+``page_size`` tiles (Mosaic pads a page shorter than the dtype's sublane
+tile; ``tests/test_tpu_compile.py`` compiles int8 pages of 16 rows);
+prefill queries are handed to the kernel head-major as [KV, S*G, hd] so
+one block is a contiguous ``(qb*G, hd)`` tile.
 
 Quantized (int8) pools add ``k_scale``/``v_scale`` operands [P, KV, 1, ps]
 bf16 — one symmetric scale per (page, kv-head, offset) row, laid out as a
@@ -85,22 +86,6 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels.common import NEG_INF
-
-#: sublane tile of a 32-bit row; packed dtypes hold 32 // bits rows per tile
-_SUBLANES = 8
-
-
-def check_page_size(page_size: int, dtype) -> None:
-    """A page block ``(1, 1, ps, hd)`` compiles for TPU only when ``ps`` is
-    a multiple of the dtype's sublane tile: 8 rows for f32, 16 for bf16,
-    32 for int8."""
-    tile = _SUBLANES * 4 // jnp.dtype(dtype).itemsize
-    if page_size % tile:
-        raise ValueError(
-            f"page_size={page_size} is not a multiple of {tile}, the TPU "
-            f"sublane tile of {jnp.dtype(dtype).name} pages: the Pallas "
-            "paged kernels cannot tile it — pick page_size as a multiple "
-            f"of {tile}")
 
 
 def _head_major_q(q):

@@ -34,8 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.paged_attention.kernel import (check_page_size,
-                                                  paged_attention_kernel,
+from repro.kernels.paged_attention.kernel import (paged_attention_kernel,
                                                   paged_mla_kernel,
                                                   paged_mla_prefill_kernel,
                                                   paged_prefill_kernel,
@@ -51,17 +50,6 @@ def _interp(interpret: bool) -> bool:
     """Kernels only lower on TPU; everywhere else ``use_kernel=True`` means
     the Pallas interpreter (correctness-identical, CI-exercisable)."""
     return interpret or jax.default_backend() != "tpu"
-
-
-def _kernel_interp(interpret: bool, pages) -> bool:
-    """``_interp`` for a kernel over ``pages`` ([P, KV, ps, hd] or latent
-    [P, ps, R]); a compiled (non-interpreted) kernel first checks that the
-    page size tiles the TPU's sublanes, so a bad ``page_size`` fails with
-    its name instead of a Mosaic block-shape error."""
-    interp = _interp(interpret)
-    if not interp:
-        check_page_size(pages.shape[-2], pages.dtype)
-    return interp
 
 
 def _meta(start, n_valid):
@@ -91,7 +79,7 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
                                  v_pages, page_table, lengths,
                                  window=window, k_scale=k_scale,
                                  v_scale=v_scale,
-                                 interpret=_kernel_interp(interpret, k_pages))
+                                 interpret=_interp(interpret))
     return out.reshape(slots, H, hd)
 
 
@@ -111,7 +99,7 @@ def paged_mla_attention(q_lat, q_rope, ckv_pages, krope_pages, page_table,
                                        scale=scale)
     return paged_mla_kernel(q_lat, q_rope, ckv_pages, krope_pages,
                             page_table, lengths, scale=scale,
-                            interpret=_kernel_interp(interpret, ckv_pages))
+                            interpret=_interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
@@ -134,7 +122,7 @@ def paged_prefill(q, k_pages, v_pages, page_table, start, n_valid, *,
     out = paged_prefill_kernel(q.reshape(S, KV, H // KV, hd), k_pages,
                                v_pages, page_table, _meta(start, n_valid),
                                k_scale=k_scale, v_scale=v_scale,
-                               interpret=_kernel_interp(interpret, k_pages))
+                               interpret=_interp(interpret))
     return out.reshape(S, H, hd)
 
 
@@ -163,8 +151,7 @@ def paged_ring_prefill(q, k_pages, v_pages, chunk_k, chunk_v, page_table,
                                     v_pages, chunk_k, chunk_v, page_table,
                                     _meta(start, n_valid), window=window,
                                     k_scale=k_scale, v_scale=v_scale,
-                                    interpret=_kernel_interp(interpret,
-                                                             k_pages))
+                                    interpret=_interp(interpret))
     return out.reshape(S, H, hd)
 
 
@@ -186,5 +173,4 @@ def paged_mla_prefill(q_lat, q_rope, ckv_pages, krope_pages, page_table,
     return paged_mla_prefill_kernel(q_lat, q_rope, ckv_pages, krope_pages,
                                     page_table, _meta(start, n_valid),
                                     scale=scale,
-                                    interpret=_kernel_interp(interpret,
-                                                             ckv_pages))
+                                    interpret=_interp(interpret))

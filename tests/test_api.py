@@ -18,7 +18,7 @@ from repro.configs.base import OptimizerConfig, RunConfig, ShapeConfig
 from repro.core.transparent import TransparentTrainer
 from repro.data.pipeline import make_input_pipeline
 from repro.data.readers import synthetic_tokens
-from repro.models import registry
+from repro.models import common, registry
 from repro.serving import ServingEngine
 
 ARCH = "stablelm-1.6b"
@@ -159,6 +159,24 @@ def test_session_serve_matches_raw_engine(mesh):
                         params=session.params,
                         mesh_cfg=api.parse_mesh(mesh))
     assert out_api == raw.generate(prompts, 4)
+
+
+def test_meshed_session_params_are_born_sharded():
+    """Under a mesh, ``Session.params`` comes out of init in the mesh
+    layout: every leaf carries its ``logical_to_mesh`` sharding over all 4
+    devices.  A whole copy on one device, sharded afterwards, does not fit
+    beside its shards at published widths."""
+    session = api.load(SERVE_ARCH, smoke=True, mesh="2x2")
+    want = common.logical_to_mesh(
+        session.bundle.specs, session.mesh,
+        common.rules_for(session.mesh_cfg, session.model))
+    split = 0
+    for leaf, sh in zip(jax.tree.leaves(session.params),
+                        jax.tree.leaves(want), strict=True):
+        assert leaf.sharding.is_equivalent_to(sh, leaf.ndim)
+        assert len(leaf.sharding.device_set) == 4
+        split += leaf.addressable_shards[0].data.size < leaf.size
+    assert split > 0                # the model axis really splits weights
 
 
 def test_session_generate_single_prompt_and_batch():

@@ -116,8 +116,11 @@ def _flash(S=1024):
                 ((KV, S, HD), BF16)]
 
 
-# (id, builder) — builders return (fn, [(shape, dtype), ...]); page sizes
-# are the sublane tile of each storage dtype (16 bf16 / 32 int8 rows)
+# (id, builder) — builders return (fn, [(shape, dtype), ...]).  Page sizes
+# are the sublane tile of each storage dtype (8 f32 / 16 bf16 / 32 int8
+# rows), and, in the "-page16"/"-page8" cases, below it: a page block
+# (1, 1, ps, hd) spans the page's whole last two dims, so the TPU takes any
+# page size — int8 pools at ServeConfig's default page_size=16 included
 CASES = [
     ("decode-bf16", lambda: _decode(16, BF16, False, 0)),
     ("decode-f32", lambda: _decode(16, jnp.float32, False, 0)),
@@ -130,6 +133,12 @@ CASES = [
     ("prefill-int8", lambda: _prefill(128, 32, BF16, True, 0)),
     ("ring-prefill-bf16", lambda: _prefill(128, 16, BF16, False, WINDOW)),
     ("ring-prefill-int8", lambda: _prefill(128, 32, BF16, True, WINDOW)),
+    ("decode-int8-page16", lambda: _decode(16, BF16, True, 0)),
+    ("decode-ring-int8-page16", lambda: _decode(16, BF16, True, WINDOW)),
+    ("prefill-int8-page16", lambda: _prefill(128, 16, BF16, True, 0)),
+    ("ring-prefill-int8-page16",
+     lambda: _prefill(128, 16, BF16, True, WINDOW)),
+    ("decode-bf16-page8", lambda: _decode(8, BF16, False, 0)),
     ("mla-decode", _mla_decode),
     ("mla-prefill", lambda: _mla_prefill(128)),
     ("flash", _flash),
@@ -144,12 +153,3 @@ def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, build):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
-
-def test_page_size_off_the_sublane_tile_is_rejected():
-    """A page block the TPU cannot tile fails by name, before Mosaic."""
-    K.check_page_size(16, BF16)
-    K.check_page_size(32, I8)
-    with pytest.raises(ValueError, match="page_size=16 .* multiple of 32"):
-        K.check_page_size(16, I8)
-    with pytest.raises(ValueError, match="page_size=8 .* multiple of 16"):
-        K.check_page_size(8, BF16)
