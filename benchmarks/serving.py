@@ -36,12 +36,12 @@ serve capacity is provisioned one page above the workload maximum — the
 for.
 
 Since the observability PR every record also carries a ``phases`` section:
-a separate *traced* pass (``ServeConfig(trace=True)`` — repro.obs spans
-with ``block_until_ready`` fencing) attributing the engine-cycle wall to
-host planning vs device prefill vs device decode vs glue.  Traced numbers
-never enter the throughput trajectory (fencing costs tokens/sec); they
-exist to explain it — e.g. whether the paged-vs-slotted gap on ROADMAP
-open item 1 is host bookkeeping or kernel time.
+a separate *traced* pass (``ServeConfig(trace=True)`` — repro.obs spans,
+unfenced) attributing the engine-cycle wall to host planning vs
+dispatching prefill and decode vs waiting on the device at retire vs glue.
+Traced numbers stay out of the throughput trajectory; they exist to
+explain it — e.g. whether the paged-vs-slotted gap on ROADMAP open item 1
+is host bookkeeping or device time.
 
 Since the sampling/spec PR the record carries a ``spec`` section:
 repetitive decode-dominated traffic (constant-token prompts drive the tiny
@@ -123,11 +123,10 @@ QUANT_ADMIT_GATE = 2.0
 #: per-arch traced-attribution section (repro.obs): where the cycle goes;
 #: ``prefill_kernel`` records whether the Pallas paged kernels (decode +
 #: chunked prefill + verify) drove the pass — backend-selected, so the
-#: trajectory's prefill_device_frac is attributable to the right path
-REQUIRED_PHASE_KEYS = ("step_time_s", "plan_frac", "prefill_device_frac",
-                       "decode_device_frac", "other_frac",
-                       "host_overhead_frac", "coverage",
-                       "decode_tokens_per_sec", "prefill_tokens_per_sec",
+#: pass's device time (retire_wait_frac) is attributable to the right path
+REQUIRED_PHASE_KEYS = ("step_time_s", "plan_frac", "prefill_dispatch_frac",
+                       "decode_dispatch_frac", "retire_wait_frac",
+                       "other_frac", "host_overhead_frac", "coverage",
                        "prefill_kernel")
 #: CI bar for host glue between device calls on the traced smoke pass —
 #: the number the pipelined submit/retire refactor drives down (was
@@ -162,7 +161,7 @@ def _untraced(summary):
     tracing off per-phase time does not exist, so those fields are
     structurally ``0.0`` — emitting them into BENCH_serving.json read as
     "host overhead is zero" / "decode throughput is zero".  Only the
-    fenced attribution pass (:func:`_traced_attribution`) reports phase
+    traced attribution pass (:func:`_traced_attribution`) reports phase
     data; the schema gate asserts the absence."""
     from repro.obs import TRACED_ONLY_KEYS
     return {k: v for k, v in summary.items() if k not in TRACED_ONLY_KEYS}
@@ -221,17 +220,17 @@ def _serve_once(arch, requests, batch, prompt_len, max_new, kv_layout,
 
 def _traced_attribution(arch, requests, batch, prompt_len, max_new,
                         page_size, trace_path=None):
-    """One *traced* pass (``ServeConfig(trace=True)``: repro.obs spans +
-    ``block_until_ready`` fencing): where the engine cycle's wall time
-    goes — host planning vs device prefill vs device decode vs glue.
+    """One *traced* pass (``ServeConfig(trace=True)``: repro.obs spans,
+    unfenced): where the engine cycle's wall time goes — host planning vs
+    dispatching prefill and decode vs waiting on the device at retire vs
+    glue.
 
-    Deliberately separate from the measured passes: fencing serializes
-    dispatch and costs throughput, so traced numbers feed the attribution
-    fractions only, never the tokens_per_sec trajectory.  Best-of-3
-    windows, same policy (and same reason) as the measured passes'
-    best-of-5: the box is shared, and a scheduler interruption between
-    fenced dispatches lands entirely in host-attributed time, so the
-    lowest-glue window is the closest to the engine's true overhead.
+    Separate from the measured passes, so the attribution fractions never
+    carry the ring's recording cost into the tokens_per_sec trajectory.
+    Best-of-3 windows, same policy (and same reason) as the measured
+    passes' best-of-5: the box is shared, and a scheduler interruption
+    lands in host-attributed time, so the lowest-glue window is the
+    closest to the engine's true overhead.
     When ``trace_path`` is set the Chrome trace JSON (Perfetto-loadable)
     of the last window is written there too."""
     import numpy as np
@@ -257,13 +256,12 @@ def _traced_attribution(arch, requests, batch, prompt_len, max_new,
         out = {
             "step_time_s": s["step_time_s"],
             "plan_frac": s["plan_time_s"] / st,
-            "prefill_device_frac": s["prefill_time_s"] / st,
-            "decode_device_frac": s["decode_time_s"] / st,
+            "prefill_dispatch_frac": s["prefill_dispatch_s"] / st,
+            "decode_dispatch_frac": s["decode_dispatch_s"] / st,
+            "retire_wait_frac": s["retire_wait_s"] / st,
             "other_frac": s["other_time_s"] / st,
             HOST_OVERHEAD_FRAC: s[HOST_OVERHEAD_FRAC],
             "coverage": phase_coverage(engine.tracer),
-            "decode_tokens_per_sec": s["decode_tokens_per_sec"],
-            "prefill_tokens_per_sec": s["prefill_tokens_per_sec"],
             "prefill_kernel": bool(engine.paged_kernel),
         }
         if best is None or out[HOST_OVERHEAD_FRAC] < best[HOST_OVERHEAD_FRAC]:
@@ -584,8 +582,8 @@ def run(**overrides):
          (r.get("quantized") or {}).get("same_budget_seq_ratio", 0.0)),
         ("serving_prefill_compile_count", 0.0, p["compile_count"]),
         ("serving_plan_time_frac", 0.0, r["phases"]["plan_frac"]),
-        ("serving_decode_device_frac", 0.0,
-         r["phases"]["decode_device_frac"]),
+        ("serving_retire_wait_frac", 0.0,
+         r["phases"]["retire_wait_frac"]),
         ("serving_host_overhead_frac", 0.0,
          r["phases"]["host_overhead_frac"]),
         ("serving_phase_coverage", 0.0, r["phases"]["coverage"]),
@@ -686,8 +684,7 @@ def main():
                   f"prefix_hit_rate={hit.get('prefix_hit_rate', 0.0):.2f} "
                   f"kv_saved={record['kv_bytes_saved_ratio']:.2f} "
                   f"phase_coverage={ph['coverage']:.2f} "
-                  f"decode_frac={ph['decode_device_frac']:.2f} "
-                  f"prefill_frac={ph['prefill_device_frac']:.2f} "
+                  f"retire_wait_frac={ph['retire_wait_frac']:.2f} "
                   f"prefill_kernel={ph['prefill_kernel']} "
                   f"host_overhead={ph['host_overhead_frac']:.2f} "
                   f"accept_rate={(sp or {}).get('accept_rate', 0.0):.2f} "

@@ -306,9 +306,12 @@ class ServeConfig:
     * ``trace`` — record per-phase engine spans, per-request lifecycle
       spans and page-pool cache events into a bounded in-memory ring
       (exportable as a Perfetto-loadable Chrome trace; per-phase seconds
-      fold into ``ServingMetrics.summary()``).  Traced mode fences device
-      calls with ``block_until_ready`` so host and device time separate —
-      that sync costs throughput, so leave it off for measured perf runs.
+      fold into ``ServingMetrics.summary()``).  Nothing is fenced: a traced
+      engine dispatches exactly like an untraced one, its ``*.dispatch``
+      spans time the enqueue and ``retire.wait`` the host blocking on the
+      device.  Whatever ``trace`` says, while a JAX profiler session runs
+      the spans also land on the profile as ``serve.*`` annotations and in
+      ``repro.obs.profiled()``.
     * ``trace_capacity`` — ring-buffer bound (events); oldest drop first.
     """
     max_batch: int = 8            # decode slots (fixed batched-decode shape)
@@ -338,7 +341,7 @@ class ServeConfig:
     enable_prefix_cache: bool = True   # share prompt-prefix pages (paged)
     prefill_bucket: bool = True        # power-of-two prefill length buckets
     prefill_chunk_tokens: int = 0      # chunked prefill size (0 = whole)
-    trace: bool = False                # repro.obs engine tracing (fenced)
+    trace: bool = False                # repro.obs engine ring (unfenced)
     trace_capacity: int = 1 << 16      # trace ring-buffer bound (events)
     # Pallas paged-attention kernels (decode + chunked prefill + verify).
     # None = auto (kernels on one TPU device, jnp gather path elsewhere,

@@ -89,9 +89,7 @@ def main():
     ap.add_argument("--trace", metavar="PATH", default="",
                     help="run the engine traced (repro.obs) and write a "
                          "Perfetto-loadable Chrome trace JSON here; also "
-                         "prints the per-phase time breakdown (fencing "
-                         "costs throughput — don't combine with measured "
-                         "runs)")
+                         "prints the per-phase time breakdown")
     args = ap.parse_args()
 
     # require the serve capability at load time: a family the engine cannot
@@ -177,13 +175,12 @@ def main():
         if s["step_time_s"] > 0:
             st = s["step_time_s"]
             print(f"  phases plan {s['plan_time_s']/st:6.1%}  "
-                  f"prefill {s['prefill_time_s']/st:6.1%}  "
-                  f"decode {s['decode_time_s']/st:6.1%}  "
+                  f"dispatch prefill {s['prefill_dispatch_s']/st:6.1%} "
+                  f"decode {s['decode_dispatch_s']/st:6.1%}  "
+                  f"retire wait {s['retire_wait_s']/st:6.1%}  "
                   f"other {s['other_time_s']/st:6.1%}  "
                   f"of {st:.2f}s engine wall "
-                  f"(host_overhead_frac {s['host_overhead_frac']:.2f}; "
-                  f"decode {s['decode_tokens_per_sec']:.1f} tok/s, "
-                  f"prefill {s['prefill_tokens_per_sec']:.1f} tok/s)")
+                  f"(host_overhead_frac {s['host_overhead_frac']:.2f})")
         for i, toks in enumerate(outs):
             print(f"  req {i}: {toks[:8]}{'...' if len(toks) > 8 else ''}")
     if args.trace:

@@ -394,6 +394,7 @@ def paged_latent_prefill_write(kv, ckv_new, krope_new, page_ids, start,
     }
 
 
+@jax.named_scope("attention")
 def paged_prefill_apply(cfg, p, x, positions, kv, page_ids, start, n_valid,
                         *, use_pallas: bool = False):
     """Prefill-chunk GQA self-attention directly against the page pool.
@@ -491,6 +492,7 @@ def paged_prefill_apply(cfg, p, x, positions, kv, page_ids, start, n_valid,
     return dot(out, p["wo"], cd), new_kv
 
 
+@jax.named_scope("attention")
 def paged_attention_apply(cfg, p, x, positions, kv, page_table, lengths, *,
                           use_pallas: bool = False):
     """One batched decode step of GQA self-attention over a paged pool.
@@ -521,6 +523,7 @@ def paged_attention_apply(cfg, p, x, positions, kv, page_table, lengths, *,
     return dot(out, p["wo"], cd), new_kv
 
 
+@jax.named_scope("attention")
 def paged_mla_attention_apply(cfg, p, x, positions, kv, page_table, lengths,
                               *, use_pallas: bool = False):
     """One batched decode step of absorbed MLA over latent pages.
@@ -558,6 +561,7 @@ def paged_mla_attention_apply(cfg, p, x, positions, kv, page_table, lengths,
     return dot(out, p["wo"], cd), new_kv
 
 
+@jax.named_scope("attention")
 def paged_mla_prefill_apply(cfg, p, x, positions, kv, page_ids, start,
                             n_valid, *, use_pallas: bool = False):
     """Prefill-chunk MLA attention directly against latent pages.
@@ -628,6 +632,7 @@ def paged_mla_prefill_apply(cfg, p, x, positions, kv, page_ids, start,
 # Full GQA block apply (projections + rope + core/window/decode dispatch)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def attention_apply(cfg, p, x, positions, *, cache=None, use_pallas: bool = False):
     """Self-attention for train/prefill (cache=None) or one decode step.
 
@@ -715,6 +720,7 @@ def _mla_q(cfg, p, x, positions, cd):
     return q_nope, q_rope
 
 
+@jax.named_scope("attention")
 def mla_apply(cfg, p, x, positions, *, cache=None):
     """Returns (out [B,S,D], new_cache_or_None)."""
     from repro.models.common import rms_norm

@@ -259,6 +259,7 @@ def norm_specs(cfg, shape_prefix=(), axes_prefix=()):
     return {"scale": ParamSpec(shape_prefix + (d,), axes_prefix + ("embed2",), init="zeros")}
 
 
+@jax.named_scope("norm")
 def apply_norm(cfg, p, x):
     if cfg.norm == "layernorm":
         return layer_norm(x, p["scale"], p["bias"], cfg.norm_eps)

@@ -26,6 +26,7 @@ def mlp_specs(cfg, d_ff: int = 0):
     }
 
 
+@jax.named_scope("mlp")
 def mlp_apply(cfg, p, x):
     act = activation(cfg.act)
     cd = x.dtype

@@ -50,6 +50,7 @@ def _choose_group_size(n_tokens: int, num_experts: int) -> int:
     return n_tokens
 
 
+@jax.named_scope("mlp")
 def moe_apply(cfg, p, x, *, n_valid=None,
               per_token: bool = False) -> Tuple[jax.Array, jax.Array]:
     """x: [B,S,D] -> (y [B,S,D], aux_loss scalar fp32).

@@ -99,6 +99,7 @@ def embed_lookup(cfg, params, tokens, compute_dtype):
     return x
 
 
+@jax.named_scope("lm_head")
 def lm_head(cfg, params, x):
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = dot(x, w, x.dtype)

@@ -5,21 +5,25 @@ operators flip ``ServeConfig(trace=True)`` (or ``--trace out.json`` on any
 launch entrypoint) and every serving cycle explains itself — phase spans on
 the engine track, lifecycle spans per request, cache events from the page
 pool — exportable as a Perfetto-loadable Chrome trace or folded into
-``ServingMetrics.summary()`` as flat per-phase seconds.
+``ServingMetrics.summary()`` as flat per-phase seconds.  While a JAX
+profiler session records, the same spans also land on the profile as
+``serve.*`` annotations and in :func:`profiled`, whatever ``trace`` says.
 
-Import discipline: this package depends on the standard library only (no
-jax, no numpy) — it sits below every serving module that emits into it.
+Import discipline: this package depends on the standard library and the
+profiler's TraceMe only (no numpy) — it sits below every serving module
+that emits into it.
 """
 from repro.obs.export import (HOST_OVERHEAD_FRAC, INFLIGHT_COUNTER,
                               LEAF_PHASES, PHASE_TIME_KEYS, STEP_SECTIONS,
                               TRACED_ONLY_KEYS, chrome_trace,
                               phase_coverage, phase_snapshot,
-                              prometheus_text, write_chrome_trace)
-from repro.obs.trace import (ENGINE_TRACK, NULL_TRACER, NullTracer, Tracer,
-                             request_track)
+                              write_chrome_trace)
+from repro.obs.trace import (ENGINE_TRACK, NULL_TRACER, NullTracer, Profiled,
+                             Tracer, profiled, request_track)
 
 __all__ = ["Tracer", "NullTracer", "NULL_TRACER", "ENGINE_TRACK",
+           "Profiled", "profiled",
            "request_track", "chrome_trace", "write_chrome_trace",
-           "phase_snapshot", "phase_coverage", "prometheus_text",
+           "phase_snapshot", "phase_coverage",
            "STEP_SECTIONS", "LEAF_PHASES", "INFLIGHT_COUNTER",
            "PHASE_TIME_KEYS", "TRACED_ONLY_KEYS", "HOST_OVERHEAD_FRAC"]

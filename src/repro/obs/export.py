@@ -13,8 +13,7 @@ Two consumers, two shapes:
   * **flat phase snapshot** (:func:`phase_snapshot`) — the per-phase time
     totals as plain floats, merged into ``ServingMetrics.summary()`` so
     one JSON record answers "where did the cycle go" without opening a
-    trace; :func:`prometheus_text` renders the same summary as a
-    Prometheus-style text exposition for scrape-shaped consumers.
+    trace.
 
 Phase model (engine track span names):
 
@@ -30,11 +29,12 @@ Phase model (engine track span names):
     cycle, so in a trace submit(N+1) begins before retire(N) ends — the
     overlap the ``engine.inflight`` counter makes visible in Perfetto;
   * the *leaves* ``plan`` (host-side prefix planning / page bookkeeping,
-    nested under whichever section triggered it), ``prefill.device``,
-    ``decode.device`` and ``verify.device`` (jitted calls, fenced with
-    ``block_until_ready`` in traced mode) are mutually disjoint, so
-    ``other = step - plan - step.draft - prefill.device - decode.device
-    - verify.device`` is the well-defined "everything else" — scheduling,
+    nested under whichever section triggered it), ``prefill.dispatch``,
+    ``decode.dispatch`` and ``verify.dispatch`` (enqueueing the jitted
+    calls — nothing is fenced, so these time the dispatch, not the device)
+    and ``retire.wait`` (retire blocking on a device result) are mutually
+    disjoint, so ``other = step - plan - step.draft - *.dispatch -
+    retire.wait`` is the well-defined "everything else" — scheduling,
     numpy glue, stream callbacks — and ``host_overhead_frac = other /
     step`` is the number the async-pipeline work drives down (gated
     <= 0.25 by the CI smoke).
@@ -50,7 +50,8 @@ from repro.obs.trace import ENGINE_TRACK
 STEP_SECTIONS = ("step.plan", "step.draft", "step.submit", "step.retire")
 
 #: disjoint leaf phases the summary attributes wall time to
-LEAF_PHASES = ("plan", "prefill.device", "decode.device", "verify.device")
+LEAF_PHASES = ("plan", "prefill.dispatch", "decode.dispatch",
+               "verify.dispatch", "retire.wait")
 
 #: Perfetto counter track: device cycles submitted but not yet retired
 INFLIGHT_COUNTER = "engine.inflight"
@@ -61,17 +62,18 @@ INFLIGHT_COUNTER = "engine.inflight"
 STEP_TIME_S = "step_time_s"
 PLAN_TIME_S = "plan_time_s"
 DRAFT_TIME_S = "draft_time_s"
-PREFILL_TIME_S = "prefill_time_s"
-DECODE_TIME_S = "decode_time_s"
-VERIFY_TIME_S = "verify_time_s"
+PREFILL_DISPATCH_S = "prefill_dispatch_s"
+DECODE_DISPATCH_S = "decode_dispatch_s"
+VERIFY_DISPATCH_S = "verify_dispatch_s"
+RETIRE_WAIT_S = "retire_wait_s"
 OTHER_TIME_S = "other_time_s"
 HOST_OVERHEAD_FRAC = "host_overhead_frac"
-PHASE_TIME_KEYS = (STEP_TIME_S, PLAN_TIME_S, DRAFT_TIME_S, PREFILL_TIME_S,
-                   DECODE_TIME_S, VERIFY_TIME_S, OTHER_TIME_S)
+PHASE_TIME_KEYS = (STEP_TIME_S, PLAN_TIME_S, DRAFT_TIME_S, PREFILL_DISPATCH_S,
+                   DECODE_DISPATCH_S, VERIFY_DISPATCH_S, RETIRE_WAIT_S,
+                   OTHER_TIME_S)
 #: phase-derived summary keys that are meaningless untraced (the traced
 #: attribution pass owns them; untraced bench records must omit them)
-TRACED_ONLY_KEYS = PHASE_TIME_KEYS + (
-    HOST_OVERHEAD_FRAC, "decode_tokens_per_sec", "prefill_tokens_per_sec")
+TRACED_ONLY_KEYS = PHASE_TIME_KEYS + (HOST_OVERHEAD_FRAC,)
 
 
 def chrome_trace(tracer, *, pid: int = 1) -> Dict[str, Any]:
@@ -138,29 +140,22 @@ def write_chrome_trace(tracer, path: str, *, pid: int = 1) -> str:
 def phase_snapshot(tracer) -> Dict[str, float]:
     """Flat per-phase attribution totals (seconds) for the summary merge.
 
-    ``*_time_s`` keys are the disjoint leaves plus the enclosing ``step``
+    ``*_s`` keys are the disjoint leaves plus the enclosing ``step``
     wall; ``other_time_s`` is step minus the leaves — host scheduling,
     numpy glue, stream callbacks.  All zeros for a :class:`NullTracer`
     (tracing off), so the summary schema is stable either way.
     """
     ph = tracer.phase_seconds
     step = ph.get("step", 0.0)
-    plan = ph.get("plan", 0.0)
-    draft = ph.get("step.draft", 0.0)
-    prefill = ph.get("prefill.device", 0.0)
-    decode = ph.get("decode.device", 0.0)
-    verify = ph.get("verify.device", 0.0)
-    other = max(step - plan - draft - prefill - decode - verify, 0.0)
-    return {
-        STEP_TIME_S: step,
-        PLAN_TIME_S: plan,
-        DRAFT_TIME_S: draft,
-        PREFILL_TIME_S: prefill,
-        DECODE_TIME_S: decode,
-        VERIFY_TIME_S: verify,
-        OTHER_TIME_S: other,
-        HOST_OVERHEAD_FRAC: (other / step) if step > 0 else 0.0,
-    }
+    leaves = {PLAN_TIME_S: ph.get("plan", 0.0),
+              DRAFT_TIME_S: ph.get("step.draft", 0.0),
+              PREFILL_DISPATCH_S: ph.get("prefill.dispatch", 0.0),
+              DECODE_DISPATCH_S: ph.get("decode.dispatch", 0.0),
+              VERIFY_DISPATCH_S: ph.get("verify.dispatch", 0.0),
+              RETIRE_WAIT_S: ph.get("retire.wait", 0.0)}
+    other = max(step - sum(leaves.values()), 0.0)
+    return {STEP_TIME_S: step, **leaves, OTHER_TIME_S: other,
+            HOST_OVERHEAD_FRAC: (other / step) if step > 0 else 0.0}
 
 
 def phase_coverage(tracer) -> float:
@@ -174,29 +169,9 @@ def phase_coverage(tracer) -> float:
     return min(sum(ph.get(s, 0.0) for s in STEP_SECTIONS) / step, 1.0)
 
 
-def prometheus_text(summary: Dict[str, Any], tracer=None,
-                    prefix: str = "repro_serving") -> str:
-    """Prometheus-style text exposition of a ``ServingMetrics.summary()``
-    dict (numeric fields only), plus per-phase seconds as one labelled
-    series when a tracer is supplied."""
-    lines = [f"# {prefix}: serving engine snapshot"]
-    for k, v in summary.items():
-        if isinstance(v, bool) or not isinstance(v, (int, float)):
-            continue
-        lines.append(f"{prefix}_{k} {v}")
-    if tracer is not None:
-        for name, secs in sorted(tracer.phase_seconds.items()):
-            lines.append(
-                f'{prefix}_phase_seconds{{phase="{name}"}} {secs}')
-            lines.append(
-                f'{prefix}_phase_calls{{phase="{name}"}} '
-                f"{tracer.phase_counts.get(name, 0)}")
-    return "\n".join(lines) + "\n"
-
-
 __all__ = ["chrome_trace", "write_chrome_trace", "phase_snapshot",
-           "phase_coverage", "prometheus_text", "STEP_SECTIONS",
-           "LEAF_PHASES", "INFLIGHT_COUNTER", "PHASE_TIME_KEYS",
-           "TRACED_ONLY_KEYS", "STEP_TIME_S", "PLAN_TIME_S",
-           "DRAFT_TIME_S", "PREFILL_TIME_S", "DECODE_TIME_S",
-           "VERIFY_TIME_S", "OTHER_TIME_S", "HOST_OVERHEAD_FRAC"]
+           "phase_coverage", "STEP_SECTIONS", "LEAF_PHASES",
+           "INFLIGHT_COUNTER", "PHASE_TIME_KEYS", "TRACED_ONLY_KEYS",
+           "STEP_TIME_S", "PLAN_TIME_S", "DRAFT_TIME_S",
+           "PREFILL_DISPATCH_S", "DECODE_DISPATCH_S", "VERIFY_DISPATCH_S",
+           "RETIRE_WAIT_S", "OTHER_TIME_S", "HOST_OVERHEAD_FRAC"]

@@ -5,29 +5,38 @@ see the runtime, but operators must be able to see *inside* it.  The
 TensorFlow whitepaper leans on built-in timeline tracing (EEG) to attribute
 step time; this module is that capability for the serving stack: every
 engine cycle decomposes into phase spans (preemption check, admission,
-chunked prefill, host planning, device decode), every request gets a
-lifecycle track (queued -> prefill chunks -> decode -> complete), and the
-page pool emits cache events (alloc, COW, ring rotation, LRU traffic,
-prefix hit/miss).
+host planning, dispatch, waiting on the device at retire), every request
+gets a lifecycle track (queued -> prefill chunks -> decode -> complete),
+and the page pool emits cache events (alloc, COW, ring rotation, LRU
+traffic, prefix hit/miss).
 
-Design constraints, in order:
+Two sinks, one span API:
 
-  * **~zero cost when off** — tracing is opt-in (``ServeConfig(trace=True)``).
-    The disabled path is :data:`NULL_TRACER`, whose methods are empty and
-    whose ``span()`` returns one shared context-manager singleton: no
-    allocation, no clock read, no branch in the engine beyond an attribute
-    call.  The hot decode loop must not regress when tracing is off.
-  * **bounded memory** — events land in a ring buffer (``capacity``, default
-    64Ki); when full the *oldest* events drop (``dropped`` counts them), so
-    a long-lived engine keeps the recent window instead of growing without
-    limit.
-  * **deterministic under test** — the clock is injectable
-    (``Tracer(clock=...)``), the same pattern ``ServingMetrics`` uses, so
-    tests drive exact timelines.
+  * **the engine's own ring** — opt-in (``ServeConfig(trace=True)``): a
+    :class:`Tracer` per engine, bounded (``capacity``, default 64Ki; when
+    full the *oldest* events drop and ``dropped`` counts them), on an
+    injectable clock (``Tracer(clock=...)``, the pattern ``ServingMetrics``
+    uses) so tests drive exact timelines.  ``save_trace`` and ``--trace``
+    export it.
+  * **the profiler's session** — whatever ``ServeConfig.trace`` says, while
+    a JAX profiler session records (``jax.profiler.start_trace``), every
+    lexical span is also a ``jax.profiler.TraceAnnotation`` named
+    ``serve.<span>`` (so it lands on the device trace's own clock, beside
+    the ops it dispatched), and spans, instants and counters also go into
+    the process-wide bounded record :func:`profiled` — as the profiler's
+    own TraceMe recorder keeps what a session captured.  Its timestamps are
+    ``time.perf_counter_ns()``, which the profile's clock is a constant
+    offset from.  It holds what was recorded since its last ``clear()``.
 
-Two kinds of span API:
+Cost when off: tracing is never fenced (a traced engine dispatches exactly
+like an untraced one), and with ``trace=False`` and no profiler session
+the disabled path is :data:`NULL_TRACER`: each emit costs one
+``TraceMe.is_enabled()`` check, and ``span()`` returns one shared
+context-manager singleton — no allocation, no clock read.
 
-  * ``with tracer.span("decode.device"): ...`` — lexically scoped phases
+Span APIs:
+
+  * ``with tracer.span("decode.dispatch"): ...`` — lexically scoped phases
     (the engine loop).  Nesting is just lexical nesting; the exporter
     renders it as stacked slices.
   * ``tracer.begin("decode", track=...)`` / ``tracer.end("decode",
@@ -35,7 +44,11 @@ Two kinds of span API:
     (a request's queued / prefill / decode lifecycle).  ``end`` of a span
     that is not open is a silent no-op (returns False), so preemption
     paths can close "whichever of prefill/decode is open" without
-    bookkeeping; balance is checked via :meth:`Tracer.open_spans`.
+    bookkeeping; balance is checked via :meth:`Tracer.open_spans`.  They
+    need per-engine open-span state, so they go to the engine's ring only;
+    the profiled record carries the lifecycle's instants
+    (``request.admit`` / ``request.first_token`` / ``request.complete``),
+    whose args hold the durations.
 
 Per-phase attribution: every closed span accumulates into
 ``phase_seconds[name]`` / ``phase_counts[name]`` *for the engine track
@@ -46,8 +59,10 @@ into the dict ``ServingMetrics.summary()`` merges.
 Events are stored as plain tuples ``(ph, name, track, ts, value, args)``
 with Chrome trace-event phase codes (``"X"`` complete span with
 ``value=duration``, ``"i"`` instant, ``"C"`` counter with
-``value=counter``); ``repro.obs.export`` turns them into a
-Perfetto-loadable Chrome trace JSON.
+``value=counter``); ``repro.obs.export`` turns a ring into a
+Perfetto-loadable Chrome trace JSON.  The ring's ``ts`` and durations are
+seconds on the tracer's clock; the profiled record's are integer
+nanoseconds of ``time.perf_counter_ns()``.
 """
 from __future__ import annotations
 
@@ -55,20 +70,73 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
+# The profiler's own TraceMe: ``is_enabled()`` is true only while a
+# profiler session records.  The one import of it in the program.
+from jax._src.lib import _profiler
+
+#: True while a JAX profiler session records (0.1-0.2 us per call)
+profiling: Callable[[], bool] = _profiler.TraceMe.is_enabled
+
 #: the engine-loop track; phase attribution accumulates spans on it only
 ENGINE_TRACK = "engine"
+
+#: prefix of the TraceAnnotation each span becomes in a profiler session
+ANNOTATION_PREFIX = "serve."
 
 #: event tuple layout (ph, name, track, ts, value, args) — ph follows the
 #: Chrome trace-event phase codes so the exporter is a dumb transcription
 Event = Tuple[str, str, str, float, float, Optional[Dict[str, Any]]]
 
 
+class Profiled:
+    """The process-wide record of what the serving stack emitted while a
+    profiler session ran: ``events`` in the ring's tuple layout with
+    ``time.perf_counter_ns()`` timestamps (span durations in ns too),
+    bounded at ``capacity`` (oldest dropped, ``dropped`` counts them).  It
+    keeps what was recorded since the last :meth:`clear`, so a reader of
+    one session clears it before that session starts."""
+
+    def __init__(self, capacity: int = 1 << 16):
+        self.events: deque = deque(maxlen=capacity)
+        self.dropped = 0
+
+    def push(self, ev: Event) -> None:
+        if len(self.events) == self.events.maxlen:
+            self.dropped += 1
+        self.events.append(ev)
+
+    def clear(self) -> None:
+        self.events.clear()
+        self.dropped = 0
+
+
+_PROFILED = Profiled()
+
+
+def profiled() -> Profiled:
+    """The process-wide record of what was emitted during profiler
+    sessions (see :class:`Profiled`)."""
+    return _PROFILED
+
+
+def _record_profiled(ph: str, name: str, track: str, value: float,
+                     args: Optional[Dict[str, Any]]) -> None:
+    """An instant or counter into :func:`profiled`, while a profiler
+    session records."""
+    if profiling():
+        _PROFILED.push((ph, name, track, time.perf_counter_ns(), value, args))
+
+
 class _SpanCtx:
-    """Lexically scoped span (``with tracer.span(...)``)."""
+    """Lexically scoped span (``with tracer.span(...)``): into the tracer's
+    ring when it records one, and — while a profiler session records — a
+    ``serve.<name>`` TraceAnnotation plus an event in :func:`profiled`."""
 
-    __slots__ = ("_tr", "_name", "_track", "_args", "_t0")
+    __slots__ = ("_tr", "_name", "_track", "_args", "_t0", "_ann", "_p0")
 
-    def __init__(self, tr: "Tracer", name: str, track: str,
+    def __init__(self, tr, name: str, track: str,
                  args: Optional[Dict[str, Any]]):
         self._tr = tr
         self._name = name
@@ -76,17 +144,31 @@ class _SpanCtx:
         self._args = args
 
     def __enter__(self) -> "_SpanCtx":
-        self._t0 = self._tr._clock()
+        if profiling():
+            self._ann = TraceAnnotation(ANNOTATION_PREFIX + self._name)
+            self._ann.__enter__()
+            self._p0 = time.perf_counter_ns()
+        else:
+            self._ann = None
+        if self._tr.enabled:
+            self._t0 = self._tr._clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         tr = self._tr
-        tr._span(self._name, self._track, self._t0, tr._clock(), self._args)
+        if tr.enabled:
+            tr._span(self._name, self._track, self._t0, tr._clock(),
+                     self._args)
+        if self._ann is not None:
+            _PROFILED.push(("X", self._name, self._track, self._p0,
+                            time.perf_counter_ns() - self._p0, self._args))
+            self._ann.__exit__(None, None, None)
         return False
 
 
 class Tracer:
-    """Bounded-ring span/counter/instant recorder with per-phase totals."""
+    """Bounded-ring span/counter/instant recorder with per-phase totals
+    (and, during a profiler session, the gated sink besides)."""
 
     enabled = True
 
@@ -160,11 +242,13 @@ class Tracer:
                 **args: Any) -> None:
         """Point event (page alloc, COW, preemption, compile, ...)."""
         self._push(("i", name, track, self._clock(), 0.0, args or None))
+        _record_profiled("i", name, track, 0, args or None)
 
     def counter(self, name: str, value: float,
                 track: str = ENGINE_TRACK) -> None:
         """Sampled counter series (queue depth, pages held, ...)."""
         self._push(("C", name, track, self._clock(), float(value), None))
+        _record_profiled("C", name, track, float(value), None)
 
     # -- inspection --------------------------------------------------------
 
@@ -200,12 +284,16 @@ _EMPTY_DICT: Dict[str, Any] = {}
 
 
 class NullTracer:
-    """Strict no-op twin of :class:`Tracer` — the disabled hot path.
+    """Ring-less twin of :class:`Tracer` — the disabled hot path, gated on
+    the profiler.
 
-    Every method returns immediately without reading the clock or
-    allocating; ``span()`` returns one module-level singleton context
-    manager.  ``events`` / ``phase_seconds`` present the empty shapes so
-    consumers (metrics merge, exporters) need no enabled-check branches.
+    With no profiler session every method returns after one
+    ``TraceMe.is_enabled()`` check, reading no clock and recording
+    nothing; ``span()`` returns one module-level singleton context
+    manager.  During a session its spans, instants and counters go to the
+    profiler and :func:`profiled` as :class:`Tracer`'s do.  ``events`` /
+    ``phase_seconds`` present the empty shapes so consumers (metrics
+    merge, exporters) need no enabled-check branches.
     """
 
     enabled = False
@@ -223,8 +311,9 @@ class NullTracer:
     def now(self) -> float:
         return 0.0
 
-    def span(self, name: str, track: str = ENGINE_TRACK,
-             **args: Any) -> _NullSpan:
+    def span(self, name: str, track: str = ENGINE_TRACK, **args: Any):
+        if profiling():
+            return _SpanCtx(self, name, track, args or None)
         return _NULL_SPAN
 
     def begin(self, name: str, track: str = ENGINE_TRACK,
@@ -236,11 +325,11 @@ class NullTracer:
 
     def instant(self, name: str, track: str = ENGINE_TRACK,
                 **args: Any) -> None:
-        pass
+        _record_profiled("i", name, track, 0, args or None)
 
     def counter(self, name: str, value: float,
                 track: str = ENGINE_TRACK) -> None:
-        pass
+        _record_profiled("C", name, track, float(value), None)
 
     def open_spans(self) -> List[Tuple[str, str]]:
         return []

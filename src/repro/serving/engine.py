@@ -25,7 +25,7 @@ Pipelined event loop (one ``step()`` = one cycle, three phases):
             once planned), so the *next* plan can run while this step is
             still executing.
   retire  — materialise the *previous* cycle's results (the only host <-
-            device sync on the untraced path) and emit its tokens in the
+            device sync, traced or not) and emit its tokens in the
             exact order the synchronous engine would have: admission
             first-tokens, chunk-completion first-tokens, then decode rows
             step-major / slot-minor.  Completion, EOS cuts, and stream
@@ -237,19 +237,16 @@ class ServingEngine:
         # (ServeConfig self-validates at construction — no re-check here)
         self.cfg = serve_cfg or ServeConfig()
         # observability: one engine-owned Tracer (ServeConfig(trace=True))
-        # threaded through scheduler, pool and metrics; NULL_TRACER keeps
-        # every emit a no-op attribute call when tracing is off.  The
-        # injectable clock is shared with metrics so deterministic tests
-        # see one consistent timeline across both.
+        # threaded through scheduler, pool and metrics; NULL_TRACER records
+        # only while a profiler session runs (one is_enabled check per emit
+        # otherwise).  Nothing is fenced either way.  The injectable clock
+        # is shared with metrics so deterministic tests see one consistent
+        # timeline across both.
         self.tracer = (Tracer(clock=clock, capacity=self.cfg.trace_capacity,
                               meta={"model": model_cfg.name,
                                     "family": model_cfg.family,
                                     "backend": jax.default_backend()})
                        if self.cfg.trace else NULL_TRACER)
-        # traced mode fences device calls (block_until_ready) so host vs
-        # device time separates; untraced, dispatch stays fully async
-        self._fence = (jax.block_until_ready if self.tracer.enabled
-                       else (lambda x: x))
         self.bundle = registry.build(model_cfg)
         caps = self.bundle.capabilities()
         if "serve" not in caps:
@@ -721,7 +718,10 @@ class ServingEngine:
             # a resumed preemptee keeps its tokens, so ``first`` is only
             # true on the genuine first emission (even if the request was
             # bounced at admission before ever running)
-            self.metrics.record_first_token(req.rid)
+            self.tracer.instant(
+                "request.first_token", track=request_track(req.rid),
+                rid=req.rid,
+                prefill_s=self.metrics.record_first_token(req.rid))
         else:
             self.metrics.record_token(req.rid)
         done = self._finished(req, token)
@@ -786,6 +786,12 @@ class ServingEngine:
     # Phase 1: plan (host only — decides, allocates, prepares; no blocking)
     # ------------------------------------------------------------------
 
+    def _admitted(self, req: Request, rt: str) -> None:
+        """Close the request's queue wait: ``request.admit`` carries it."""
+        self.tracer.instant("request.admit", track=rt, rid=req.rid,
+                            queue_s=self.metrics.record_admit(req.rid))
+        self.tracer.end("queued", track=rt)
+
     def _plan_admit(self, req: Request, admits: List[_AdmitPlan]) -> bool:
         """Reserve a slot (and pages) for one request; False when the pool
         cannot take it right now (the caller re-queues it, never drops
@@ -806,7 +812,7 @@ class ServingEngine:
             slot, cached = out
             if cached:
                 self.metrics.record_prefix_hit(cached)
-            self.tracer.end("queued", track=rt)
+            self._admitted(req, rt)
             self.tracer.begin("prefill", track=rt,
                               prompt_tokens=len(prompt),
                               prefix_hit_tokens=cached)
@@ -826,7 +832,7 @@ class ServingEngine:
             slot = self.pool.alloc(req.rid)
         if slot is None:
             return False
-        self.tracer.end("queued", track=rt)
+        self._admitted(req, rt)
         self.tracer.begin("prefill", track=rt, prompt_tokens=len(prompt))
         admits.append(_AdmitPlan(req, slot, prompt))
         return True
@@ -1067,7 +1073,7 @@ class ServingEngine:
                 tr.begin("decode", track=rt)
                 continue
             toks, n_valid = self._bucketed_prompt(a.prompt, self._cache_len)
-            with tr.span("prefill.device", tokens=len(a.prompt),
+            with tr.span("prefill.dispatch", tokens=len(a.prompt),
                          bucket=int(toks.shape[1])):
                 if n_valid is None:
                     logits, state = self._prefill(self.params, toks,
@@ -1076,7 +1082,6 @@ class ServingEngine:
                     logits, state = self._prefill(
                         self.params, toks, cache_len=self._cache_len,
                         n_valid=jnp.asarray(n_valid, jnp.int32))
-                self._fence(logits)
             self.metrics.record_prefill(len(a.prompt))
             if self.paged:
                 self.pool.insert_state(a.slot, state)
@@ -1103,7 +1108,8 @@ class ServingEngine:
             rt = request_track(job.req.rid)
             with tr.span("prefill.chunk", track=rt, chunk=c.chunk,
                          bucket=width, start=c.start):
-                with tr.span("prefill.device", tokens=c.chunk, bucket=width):
+                with tr.span("prefill.dispatch", tokens=c.chunk,
+                             bucket=width):
                     # the table row uploads as a copy (jnp.array, see
                     # PagedKVCachePool.insert_state)
                     logits, self.pool.pages = self._paged_prefill(
@@ -1111,7 +1117,6 @@ class ServingEngine:
                         jnp.array(self.pool.tables[c.slot]),
                         jnp.asarray(c.start, jnp.int32),
                         jnp.asarray(c.chunk, jnp.int32))
-                    self._fence(logits)
             self.metrics.record_prefill(c.chunk)
             job.done = c.start + c.chunk
             # register fully-written blocks right away: requests admitted
@@ -1151,7 +1156,7 @@ class ServingEngine:
                                  if s in self.pool.owner else GREEDY)
                      for s in range(cfg.max_batch)])
                 samp_dev = jnp.asarray(samp)
-            with tr.span("decode.device", steps=cfg.decode_steps,
+            with tr.span("decode.dispatch", steps=cfg.decode_steps,
                          rows=len(plan.rows), sampled=sampled):
                 if self.paged:
                     packed = self.pool.decode_operands(
@@ -1181,7 +1186,6 @@ class ServingEngine:
                         stack, self._last_toks_dev, self.pool.state = \
                             self._decode(self.params, self._last_toks_dev,
                                          self.pool.state, limits_dev)
-                self._fence(stack)
             if self.paged:
                 # host positions are deterministic once planned — advance
                 # now so the next plan overlaps the in-flight device step
@@ -1219,11 +1223,10 @@ class ServingEngine:
                 packed[i, tw + 2 + PACKED_WIDTH:
                        tw + 2 + PACKED_WIDTH + sp.m] = sp.drafts
                 total += sp.m + 1
-            with tr.span("verify.device", tokens=total, rows=n):
+            with tr.span("verify.dispatch", tokens=total, rows=n):
                 emit, nacc, self.pool.pages = self._verify(
                     self.params, jnp.asarray(toks), self.pool.pages,
                     jnp.asarray(packed))
-                self._fence(emit)
             for sp in plan.specs:
                 # optimistic host advance over the whole drafted span;
                 # retire rewinds past the first mismatch
@@ -1256,14 +1259,22 @@ class ServingEngine:
             self._pending.pop(rid, None)
 
     def _retire(self, inf: _InFlight, stream: Optional[StreamFn]) -> None:
-        stack = np.asarray(inf.stack) if inf.stack is not None else None
+        # every host <- device sync of the engine sits in a ``retire.wait``
+        # span: the time the host waited for the device
+        tr = self.tracer
+        stack = None
+        if inf.stack is not None:
+            with tr.span("retire.wait"):
+                stack = np.asarray(inf.stack)
         emitted: List[int] = []
         for rid, slot, tok in inf.overrides:
             if rid in self.results:
                 continue
             req = self.requests[rid]
             emitted.append(rid)
-            if self._emit(req, int(tok), stream):
+            with tr.span("retire.wait"):
+                tok = int(tok)
+            if self._emit(req, tok, stream):
                 self._finalize(slot, req)
         # decode rows emit step-major / slot-minor — the synchronous
         # engine's per-step completion sweep order.  Rows past a slot's
@@ -1286,8 +1297,9 @@ class ServingEngine:
         # batched forward instead of sum(nacc + 1) steps
         if inf.specs is not None:
             sps, emit_dev, nacc_dev = inf.specs
-            emit_all = np.asarray(emit_dev)
-            nacc_all = np.asarray(nacc_dev)
+            with tr.span("retire.wait"):
+                emit_all = np.asarray(emit_dev)
+                nacc_all = np.asarray(nacc_dev)
             for i, sp in enumerate(sps):
                 req, slot = sp.req, sp.slot
                 self._spec_wait.discard(slot)
@@ -1384,13 +1396,15 @@ class ServingEngine:
         device is never idle waiting for planning.  ``pipeline_depth=1``:
         retire what was just submitted (synchronous semantics).
 
-        Traced (``ServeConfig(trace=True)``), the cycle decomposes into
-        the section spans of ``repro.obs.export.STEP_SECTIONS``
-        (``step.plan`` / ``step.submit`` / ``step.retire`` tile the
-        enclosing ``step`` span) and the device calls are fenced with
-        ``block_until_ready`` so host vs device time separates.
-        Untraced, every ``with tracer.span(...)`` is the shared no-op
-        context manager and no fence runs.
+        Traced (``ServeConfig(trace=True)``, or during a profiler session
+        whatever ``trace`` says), the cycle decomposes into the section
+        spans of ``repro.obs.export.STEP_SECTIONS`` (``step.plan`` /
+        ``step.submit`` / ``step.retire`` tile the enclosing ``step``
+        span); ``*.dispatch`` spans time enqueueing device work and
+        ``retire.wait`` the host blocking on its results — nothing is
+        fenced, so a traced cycle dispatches exactly like an untraced one.
+        Untraced and unprofiled, every ``with tracer.span(...)`` is the
+        shared no-op context manager.
         """
         tr = self.tracer
         with tr.span("step"):
@@ -1398,7 +1412,14 @@ class ServingEngine:
                 plan = self._plan_cycle()
             with tr.span("step.draft", rows=len(plan.rows)):
                 self._plan_spec(plan)
-            with tr.span("step.submit"):
+            # prompt tokens dispatched this cycle, beside the decode rows:
+            # the mixed cycles whose prefill delays the decode scan
+            prefill_tokens = (
+                sum(len(a.prompt) for a in plan.admits
+                    if a.cached_tok is None)
+                + sum(c.chunk for c in plan.chunks))
+            with tr.span("step.submit", prefill_tokens=prefill_tokens,
+                         decode_rows=len(plan.rows)):
                 nxt = self._submit(plan)
                 prev, self._inflight = self._inflight, nxt
                 if prev is not None or nxt is not None:

@@ -8,8 +8,8 @@ is injectable so tests can drive deterministic timelines.
 ``launch/serve.py`` (pretty print) and ``benchmarks/serving.py``
 (BENCH_serving.json trajectory).  When the engine runs traced
 (``ServeConfig(trace=True)``) the attached ``repro.obs.Tracer``'s per-phase
-seconds fold into the same dict — plan / prefill / decode / other wall
-time, and the prefill-vs-decode throughput split those times enable.
+seconds fold into the same dict — plan / dispatch / retire-wait / other
+wall time.
 """
 from __future__ import annotations
 
@@ -18,7 +18,6 @@ import time
 from typing import Callable, Dict, List, Optional, Union
 
 from repro.obs import NULL_TRACER, phase_snapshot
-from repro.obs.export import DECODE_TIME_S, PREFILL_TIME_S
 
 
 def percentile(xs: List[float], p: float) -> float:
@@ -39,8 +38,9 @@ def percentile(xs: List[float], p: float) -> float:
 class ServingMetrics:
     """Per-request latency + engine throughput counters.
 
-    Timeline per request: submit -> first_token (TTFT, covers queueing +
-    prefill) -> token* (inter-token latency) -> completion.
+    Timeline per request: submit -> admit (queue wait) -> first_token
+    (TTFT, covers queueing + prefill) -> token* (inter-token latency) ->
+    completion.  A preempted request queues again from its requeue.
 
     ``tracer`` (a ``repro.obs.Tracer`` / ``NULL_TRACER``) is attached by
     the engine; ``summary()`` folds its per-phase seconds in.  The tracer
@@ -58,6 +58,8 @@ class ServingMetrics:
         """Zero every counter (benchmarks reuse warm engines).  The
         attached tracer is NOT reset — it is engine-owned state."""
         self._submit_t: Dict[int, float] = {}
+        self._queued_t: Dict[int, float] = {}      # submit or last requeue
+        self._admit_t: Dict[int, float] = {}
         self._last_token_t: Dict[int, float] = {}
         self.ttft: List[float] = []
         self.itl: List[float] = []                 # inter-token latencies
@@ -87,6 +89,14 @@ class ServingMetrics:
         if self._t_start is None:
             self._t_start = t
         self._submit_t[rid] = t
+        self._queued_t[rid] = t
+
+    def record_admit(self, rid: int) -> float:
+        """The request got a slot; returns its wait since it was submitted
+        or last requeued (seconds)."""
+        t = self.now()
+        self._admit_t[rid] = t
+        return t - self._queued_t.get(rid, t)
 
     def record_reject(self) -> None:
         self.rejected += 1
@@ -98,8 +108,7 @@ class ServingMetrics:
 
     def record_decode_token(self) -> None:
         """A token produced by a batched *decode* step (as opposed to the
-        token a prefill's final logits emit) — the numerator of
-        ``decode_tokens_per_sec``."""
+        token a prefill's final logits emit)."""
         self.decode_tokens += 1
 
     def record_spec(self, drafted: int, accepted: int) -> None:
@@ -122,13 +131,16 @@ class ServingMetrics:
         growth here is the per-prompt-length jit explosion."""
         self.prefill_compiles += 1
 
-    def record_first_token(self, rid: int) -> None:
+    def record_first_token(self, rid: int) -> float:
+        """Returns the time since the request's last admission (seconds):
+        its prefill and the wait until the token was retired."""
         t = self.now()
         if rid in self._submit_t:
             self.ttft.append(t - self._submit_t[rid])
         self._last_token_t[rid] = t
         self.tokens_out += 1
         self._t_end = t
+        return t - self._admit_t.get(rid, t)
 
     def record_token(self, rid: int) -> None:
         t = self.now()
@@ -143,16 +155,20 @@ class ServingMetrics:
         self.completed += 1
         self._t_end = self.now()
         self._submit_t.pop(rid, None)
+        self._queued_t.pop(rid, None)
+        self._admit_t.pop(rid, None)
         self._last_token_t.pop(rid, None)
 
     def record_preemption(self, rid: Optional[int] = None) -> None:
         """A running request was evicted.  Dropping its last-token timestamp
         keeps eviction + re-queue + re-prefill time *out* of inter-token
         latency: the first token after resume sets a fresh baseline instead
-        of recording the whole preemption gap as one giant ITL sample."""
+        of recording the whole preemption gap as one giant ITL sample.  The
+        request queues again from now (its next admission's wait)."""
         self.preemptions += 1
         if rid is not None:
             self._last_token_t.pop(rid, None)
+            self._queued_t[rid] = self.now()
 
     def drop_itl_baseline(self, rid: int) -> None:
         """Forget a request's last-token timestamp without counting a
@@ -191,16 +207,9 @@ class ServingMetrics:
     def summary(self) -> Dict[str, Union[int, float]]:
         """Flat JSON-friendly export (int counters + float gauges; an
         earlier annotation claimed all-float).  Rate fields divide by
-        ``elapsed()`` / traced phase seconds and report 0.0 whenever the
-        denominator is 0 — a rejected-everything run or an untraced engine
-        yields honest zeros, never a ZeroDivisionError.
-
-        ``decode_tokens_per_sec`` / ``prefill_tokens_per_sec`` split the
-        combined ``tokens_per_sec`` (kept for BENCH comparability) by the
-        tracer's accumulated device-phase time: decode tokens over decode
-        kernel seconds, prefill tokens *run* (prefix hits excluded) over
-        prefill kernel seconds.  Both are 0.0 with tracing off — per-phase
-        time does not exist untraced.
+        ``elapsed()`` and report 0.0 whenever it is 0 — a
+        rejected-everything run yields honest zeros, never a
+        ZeroDivisionError.
         """
         dt = self.elapsed()
         prompt_tokens = self.prefill_tokens + self.prefix_hit_tokens
@@ -209,7 +218,6 @@ class ServingMetrics:
         # the async-pipeline acceptance number
         phases = phase_snapshot(self.tracer if self.tracer is not None
                                 else NULL_TRACER)
-        dec_t, pre_t = phases[DECODE_TIME_S], phases[PREFILL_TIME_S]
         return {
             "completed": self.completed,
             "tokens_out": self.tokens_out,
@@ -225,10 +233,6 @@ class ServingMetrics:
             "compile_count": self.prefill_compiles,
             "elapsed_s": dt,
             "tokens_per_sec": (self.tokens_out / dt) if dt > 0 else 0.0,
-            "decode_tokens_per_sec": (self.decode_tokens / dec_t
-                                      if dec_t > 0 else 0.0),
-            "prefill_tokens_per_sec": (self.prefill_tokens / pre_t
-                                       if pre_t > 0 else 0.0),
             **phases,
             "ttft_mean_s": sum(self.ttft) / len(self.ttft) if self.ttft else 0.0,
             "ttft_p50_s": percentile(self.ttft, 50),

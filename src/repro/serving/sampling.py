@@ -86,6 +86,7 @@ def pack_params(p: SamplingParams) -> np.ndarray:
     ], dtype=np.int32)
 
 
+@jax.named_scope("sampler")
 def sample_tokens(logits, packed, idx):
     """Sample one token per row.  Pure function of (logits, packed, idx).
 

@@ -6,14 +6,22 @@ Four layers of guarantees:
     close_all), bounded ring buffer, engine-track-only phase accounting;
   * exporters — Chrome trace-event schema validity (every event carries
     ph/ts/pid/tid; one track per request; metadata names), phase snapshot
-    / coverage math, Prometheus text;
+    / coverage math;
   * disabled path — NULL_TRACER is a strict no-op (shared span singleton,
-    no events, zero phase time) and an untraced engine records nothing;
+    no events, zero phase time) and an untraced engine records nothing,
+    creating no profiler annotation, while no profiler session runs;
   * end-to-end — a traced engine run keeps every lifecycle span balanced
     through preemption and chunked prefill, its section spans cover
-    >= 95% of the engine-loop wall, and the emitted trace loads as JSON.
+    >= 95% of the engine-loop wall, the emitted trace loads as JSON, and
+    nothing is fenced;
+  * profiler sessions — an untraced engine's spans land on the profile as
+    ``serve.*`` annotations, a constant offset from the process-wide
+    record ``profiled()``, which holds the counters the benchmark reads;
+    the per-request queue / prefill split is exact on a fake clock; the
+    model's layer kinds are named scopes in the lowered program.
 """
 import json
+import re
 
 import jax
 import numpy as np
@@ -23,7 +31,7 @@ from repro.configs import ServeConfig, get_config
 from repro.models import registry
 from repro.obs import (ENGINE_TRACK, NULL_TRACER, NullTracer, Tracer,
                        chrome_trace, phase_coverage, phase_snapshot,
-                       prometheus_text, request_track, write_chrome_trace)
+                       profiled, request_track, write_chrome_trace)
 from repro.serving import ServingEngine, ServingMetrics
 
 
@@ -63,12 +71,12 @@ def test_tracer_nested_spans_accumulate_independently():
     clk = FakeClock()
     tr = Tracer(clock=clk)
     with tr.span("step"):                       # t=2 .. t=5
-        with tr.span("decode.device"):          # t=3 .. t=4
+        with tr.span("decode.dispatch"):          # t=3 .. t=4
             pass
     assert tr.phase_seconds["step"] == 3.0
-    assert tr.phase_seconds["decode.device"] == 1.0
+    assert tr.phase_seconds["decode.dispatch"] == 1.0
     # inner span closes first: events land in completion order
-    assert [e[1] for e in tr.events] == ["decode.device", "step"]
+    assert [e[1] for e in tr.events] == ["decode.dispatch", "step"]
 
 
 def test_tracer_request_track_spans_do_not_count_as_phases():
@@ -138,7 +146,7 @@ def _fake_traced_run():
     with tr.span("step"):
         with tr.span("admit"):
             tr.instant("pool.page_alloc", page=1, slot=0)
-        with tr.span("decode.device"):
+        with tr.span("decode.dispatch"):
             pass
         tr.counter("queue_depth", 3)
     tr.begin("decode", track=request_track(0))
@@ -186,29 +194,20 @@ def test_phase_snapshot_and_coverage_math():
             with tr.span("plan"):                # leaf inside a section
                 pass
         with tr.span("step.submit"):             # section: 3 ticks
-            with tr.span("decode.device"):       # leaf: 1 tick
+            with tr.span("decode.dispatch"):     # leaf: 1 tick
                 pass
         with tr.span("step.retire"):             # section: 1 tick
             pass
     snap = phase_snapshot(tr)
     assert snap["step_time_s"] == 11.0
     assert snap["plan_time_s"] == 1.0
-    assert snap["decode_time_s"] == 1.0
-    assert snap["prefill_time_s"] == 0.0
+    assert snap["decode_dispatch_s"] == 1.0
+    assert snap["prefill_dispatch_s"] == 0.0
     assert snap["other_time_s"] == 9.0           # step - leaves
     assert snap["host_overhead_frac"] == pytest.approx(9.0 / 11.0)
     # coverage counts the pipeline sections (3 + 3 + 1 = 7) over step
     assert phase_coverage(tr) == pytest.approx(7.0 / 11.0)
     assert phase_coverage(Tracer(clock=FakeClock())) == 1.0   # nothing traced
-
-
-def test_prometheus_text_exposition():
-    tr = _fake_traced_run()
-    m = ServingMetrics(clock=FakeClock(), tracer=tr)
-    txt = prometheus_text(m.summary(), tr)
-    assert "repro_serving_tokens_per_sec 0.0" in txt
-    assert 'repro_serving_phase_seconds{phase="step"}' in txt
-    assert 'repro_serving_phase_calls{phase="decode.device"} 1' in txt
 
 
 # ---------------------------------------------------------------------------
@@ -231,41 +230,26 @@ def test_null_tracer_is_strict_noop():
     # exporters accept it without branches
     assert phase_snapshot(n) == {"step_time_s": 0.0, "plan_time_s": 0.0,
                                  "draft_time_s": 0.0,
-                                 "prefill_time_s": 0.0, "decode_time_s": 0.0,
-                                 "verify_time_s": 0.0,
+                                 "prefill_dispatch_s": 0.0,
+                                 "decode_dispatch_s": 0.0,
+                                 "verify_dispatch_s": 0.0,
+                                 "retire_wait_s": 0.0,
                                  "other_time_s": 0.0,
                                  "host_overhead_frac": 0.0}
     assert phase_coverage(n) == 1.0
 
 
 def test_metrics_summary_stable_schema_untraced():
-    """Rate splits report honest zeros untraced; int counters stay ints;
-    a rejected-everything run divides nothing by zero."""
+    """Rates and phase times report honest zeros untraced; int counters
+    stay ints; a rejected-everything run divides nothing by zero."""
     m = ServingMetrics(clock=FakeClock())      # tracer=None -> NULL path
     m.record_reject()
     s = m.summary()
     assert s["rejected"] == 1 and s["elapsed_s"] == 0.0
     assert s["tokens_per_sec"] == 0.0
-    assert s["decode_tokens_per_sec"] == 0.0
-    assert s["prefill_tokens_per_sec"] == 0.0
     assert s["step_time_s"] == 0.0 and s["other_time_s"] == 0.0
     assert isinstance(s["completed"], int)
     assert isinstance(s["decode_tokens"], int)
-
-
-def test_metrics_split_rates_use_traced_phase_time():
-    tr = Tracer(clock=FakeClock())
-    m = ServingMetrics(clock=FakeClock(), tracer=tr)
-    with tr.span("decode.device"):              # 1 fake second
-        pass
-    with tr.span("prefill.device"):             # 1 fake second
-        pass
-    m.record_prefill(8)
-    for _ in range(4):
-        m.record_decode_token()
-    s = m.summary()
-    assert s["decode_tokens_per_sec"] == 4.0
-    assert s["prefill_tokens_per_sec"] == 8.0
 
 
 # ---------------------------------------------------------------------------
@@ -291,7 +275,7 @@ def test_engine_untraced_records_nothing(dense_setup):
     assert eng.tracer.events == ()
     assert eng.save_trace("/nonexistent/never-written.json") is None
     s = eng.metrics.summary()
-    assert s["step_time_s"] == 0.0 and s["decode_tokens_per_sec"] == 0.0
+    assert s["step_time_s"] == 0.0 and s["retire_wait_s"] == 0.0
 
 
 def test_engine_traced_spans_balance_and_cover(dense_setup, tmp_path):
@@ -317,14 +301,17 @@ def test_engine_traced_spans_balance_and_cover(dense_setup, tmp_path):
     s = eng.metrics.summary()
     assert s["step_time_s"] > 0
     assert s["step_time_s"] == pytest.approx(
-        s["plan_time_s"] + s["draft_time_s"] + s["prefill_time_s"]
-        + s["decode_time_s"] + s["verify_time_s"] + s["other_time_s"])
+        s["plan_time_s"] + s["draft_time_s"] + s["prefill_dispatch_s"]
+        + s["decode_dispatch_s"] + s["verify_dispatch_s"]
+        + s["retire_wait_s"] + s["other_time_s"])
     # every decode-loop token is attributed; first tokens come from prefill
     assert s["decode_tokens"] == s["tokens_out"] - s["completed"]
-    assert s["decode_tokens_per_sec"] > 0 and s["prefill_tokens_per_sec"] > 0
+    assert s["decode_dispatch_s"] > 0 and s["prefill_dispatch_s"] > 0
+    assert s["retire_wait_s"] > 0
     names = {e[1] for e in tr.events}
     assert {"step", "step.plan", "step.submit", "step.retire", "admit",
-            "prefill", "decode.device", "plan", "prefill.device",
+            "prefill", "decode.dispatch", "plan", "prefill.dispatch",
+            "retire.wait", "request.admit", "request.first_token",
             "prefill.chunk", "queued", "decode", "request.complete",
             "pool.page_alloc", "pool.prefix_hit"} <= names
     # one lifecycle track per request, all schema-valid
@@ -385,3 +372,188 @@ def test_session_trace_passthrough(tmp_path):
     p = sess.save_trace(str(tmp_path / "s.json"))
     doc = json.loads((tmp_path / "s.json").read_text())
     assert p and doc["traceEvents"]
+
+
+# ---------------------------------------------------------------------------
+# No fence, and the profiler-gated path
+# ---------------------------------------------------------------------------
+
+def test_profiler_gate_is_tracemes_own_check():
+    """The gate is the profiler's own TraceMe.is_enabled, imported from
+    jax._src.lib._profiler in one place; off outside a session."""
+    from jax._src.lib import _profiler
+
+    from repro.obs import trace as obs_trace
+    assert obs_trace.profiling is _profiler.TraceMe.is_enabled
+    assert obs_trace.profiling() is False
+
+
+def test_traced_engine_never_blocks_until_ready(dense_setup, monkeypatch):
+    """A traced serve dispatches exactly like an untraced one: it never
+    calls jax.block_until_ready (the sync is retire's np.asarray)."""
+    cfg, params = dense_setup
+    calls = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append(1) or real(x))
+    scfg = ServeConfig(max_batch=2, max_seq_len=64, max_new_tokens=6,
+                       decode_steps=2, page_size=8, prefill_chunk_tokens=8,
+                       trace=True)
+    eng = ServingEngine(cfg, scfg, params=params)
+    rng = np.random.default_rng(2)
+    outs = eng.generate(_prompts(rng, cfg.vocab_size, [5, 20, 9]), 6)
+    assert calls == []
+    assert eng.tracer.phase_counts["retire.wait"] > 0
+    assert outs == ServingEngine(cfg, scfg.replace(trace=False),
+                                 params=params).generate(
+        _prompts(np.random.default_rng(2), cfg.vocab_size, [5, 20, 9]), 6)
+
+
+def test_untraced_unprofiled_engine_creates_no_annotation(dense_setup,
+                                                          monkeypatch):
+    """trace=False and no profiler session: nothing recorded anywhere,
+    and no TraceAnnotation is ever created."""
+    from repro.obs import trace as obs_trace
+    made = []
+
+    class Counting(obs_trace.TraceAnnotation):
+        def __init__(self, *a, **k):
+            made.append(a)
+            super().__init__(*a, **k)
+
+    monkeypatch.setattr(obs_trace, "TraceAnnotation", Counting)
+    profiled().clear()
+    cfg, params = dense_setup
+    eng = ServingEngine(cfg, ServeConfig(max_batch=2, max_seq_len=32,
+                                         max_new_tokens=4, decode_steps=2,
+                                         page_size=8), params=params)
+    eng.generate(_prompts(np.random.default_rng(0), cfg.vocab_size, [5, 9]),
+                 4)
+    assert made == []
+    assert len(profiled().events) == 0 and eng.tracer.events == ()
+
+
+@pytest.fixture(scope="module")
+def profiled_serve(dense_setup, tmp_path_factory):
+    """One untraced serve under a CPU profiler session: the record it
+    left in profiled(), and the serve.* host events of the .xplane.pb."""
+    from jax.profiler import ProfileData
+    cfg, params = dense_setup
+    scfg = ServeConfig(max_batch=2, max_seq_len=64, max_new_tokens=6,
+                       decode_steps=2, page_size=8)
+    eng = ServingEngine(cfg, scfg, params=params)
+    rng = np.random.default_rng(4)
+    # compile outside the session; fresh prompts of the same lengths then
+    # run the same programs with no prefix-cache hits
+    eng.generate(_prompts(rng, cfg.vocab_size, [5, 12, 9]), 6)
+    log_dir = tmp_path_factory.mktemp("prof")
+    profiled().clear()
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        eng.generate(_prompts(rng, cfg.vocab_size, [5, 12, 9]), 6)
+    finally:
+        jax.profiler.stop_trace()
+    events = list(profiled().events)
+    profiled().clear()
+    pd = ProfileData.from_file(str(next(log_dir.rglob("*.xplane.pb"))))
+    host = [(e.name, e.start_ns, e.duration_ns) for plane in pd.planes
+            if plane.name.startswith("/host:CPU") for line in plane.lines
+            for e in line.events if e.name.startswith("serve.")]
+    return events, host
+
+
+def test_profiled_record_holds_engine_spans_and_counters(profiled_serve):
+    events, _ = profiled_serve
+    names = {(e[0], e[1]) for e in events}
+    assert {("X", "step"), ("X", "step.submit"), ("X", "retire.wait"),
+            ("i", "request.admit"),
+            ("i", "request.first_token")} <= names
+    admits = [e[5] for e in events if e[1] == "request.admit"]
+    firsts = [e[5] for e in events if e[1] == "request.first_token"]
+    assert len(admits) == len(firsts) == 3
+    assert all(a["queue_s"] >= 0 for a in admits)
+    assert all(f["prefill_s"] > 0 for f in firsts)
+    subs = [e[5] for e in events if e[1] == "step.submit"]
+    assert sum(a["prefill_tokens"] for a in subs) == 5 + 12 + 9
+    assert any(a["decode_rows"] > 0 for a in subs)
+    # spans carry perf_counter_ns stamps and ns durations
+    steps = [e for e in events if e[1] == "step"]
+    assert all(isinstance(e[3], int) and e[4] > 0 for e in steps)
+
+
+def test_profile_spans_share_the_records_clock(profiled_serve):
+    """Each serve.step event of the profile's host plane starts a constant
+    offset from its record's perf_counter_ns start, within 50 us."""
+    events, host = profiled_serve
+    rec = sorted(e[3] for e in events if e[0] == "X" and e[1] == "step")
+    prof = sorted(s for n, s, _ in host if n == "serve.step")
+    assert len(prof) == len(rec) > 3
+    offsets = [p - r for p, r in zip(prof, rec)]
+    assert max(offsets) - min(offsets) < 50_000
+    assert {"serve.step.plan", "serve.step.submit", "serve.retire.wait",
+            "serve.decode.dispatch"} <= {n for n, _, _ in host}
+
+
+def test_queue_and_prefill_split_ttft_exactly():
+    """On a fake clock queue_s + prefill_s is submit-to-first-token
+    exactly, and a preempted request's next admission waits from its
+    requeue, not from its submit."""
+    clk = FakeClock()
+    m = ServingMetrics(clock=clk)
+    m.record_submit(0)                          # t=1
+    clk.t = 4.0
+    assert m.record_admit(0) == 4.0             # t=5
+    clk.t = 9.0
+    assert m.record_first_token(0) == 5.0       # t=10
+    assert m.ttft == [9.0] == [4.0 + 5.0]
+    clk.t = 19.0
+    m.record_preemption(0)                      # requeued at t=20
+    clk.t = 26.0
+    assert m.record_admit(0) == 7.0             # t=27
+
+
+def test_engine_admit_and_first_token_events_on_a_fake_clock(dense_setup):
+    """The engine's request.admit / request.first_token events carry the
+    split: per request, queue_s + prefill_s is its TTFT exactly; a
+    preempted request's second admission waits from its requeue."""
+    cfg, params = dense_setup
+    base = ServeConfig(max_batch=2, max_seq_len=32, max_new_tokens=12,
+                       decode_steps=2, kv_layout="paged", page_size=4,
+                       num_pages=12, trace=True)
+    eng = ServingEngine(cfg, base, params=params, clock=FakeClock())
+    eng.generate(_prompts(np.random.default_rng(11), cfg.vocab_size,
+                          [14, 15]), 12)
+    assert eng.metrics.preemptions >= 1
+    evs = eng.tracer.events
+    admits = [e for e in evs if e[1] == "request.admit"]
+    firsts = {e[5]["rid"]: e[5]["prefill_s"] for e in evs
+              if e[1] == "request.first_token"}
+    first_admit = {}
+    for e in admits:
+        first_admit.setdefault(e[5]["rid"], e[5]["queue_s"])
+    split = sorted(first_admit[r] + firsts[r] for r in firsts)
+    assert split == sorted(eng.metrics.ttft)
+    # the victim's re-admission: its wait runs from the preemption
+    preempt = next(e for e in evs if e[1] == "request.preempt")
+    rid = preempt[5]["rid"]
+    again = [e for e in admits if e[5]["rid"] == rid][1]
+    requeued_at = again[3] - again[5]["queue_s"]
+    assert abs(requeued_at - preempt[3]) <= 3  # a few clock reads apart
+    assert again[5]["queue_s"] < again[3] - admits[0][3]
+
+
+def test_lowered_decode_scan_names_its_layer_kinds(dense_setup):
+    """The shared block and the sampler run under named scopes, so the
+    device trace's op metadata says which layer kind each fusion is."""
+    cfg, params = dense_setup
+    from repro.serving.sampling import GREEDY, pack_params
+    eng = ServingEngine(cfg, ServeConfig(max_batch=2, max_seq_len=32,
+                                         page_size=8), params=params)
+    packed = eng.pool.decode_operands({0: 4}, mask_slots=(1,))
+    samp = jax.numpy.asarray(np.stack([pack_params(GREEDY)] * 2))
+    hlo = eng._decode_sampled.lower(
+        eng.params, eng._last_toks_dev, eng.pool.pages, packed,
+        samp).compile().as_text()
+    op_names = " ".join(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in ("attention", "mlp", "norm", "lm_head", "sampler"):
+        assert f"/{scope}/" in op_names, scope

@@ -310,10 +310,10 @@ def test_spec_acceptance_happens():
 
 
 def test_spec_traced_phases_and_metrics(tmp_path):
-    """Traced spec run: step.draft and verify.device appear, the section
+    """Traced spec run: step.draft and verify.dispatch appear, the section
     spans still tile the step (coverage >= 0.95), and the spec counters
-    flow through summary() and prometheus_text."""
-    from repro.obs import phase_coverage, prometheus_text
+    flow through summary()."""
+    from repro.obs import phase_coverage
     cfg = _cfg("full")
     eng = _engine(cfg, depth=2, max_seq_len=64, max_new_tokens=24,
                   spec_tokens=8, trace=True)
@@ -322,10 +322,7 @@ def test_spec_traced_phases_and_metrics(tmp_path):
     assert tr.open_spans() == []
     assert phase_coverage(tr) >= 0.95
     names = {e[1] for e in tr.events}
-    assert {"step.draft", "verify.device"} <= names
+    assert {"step.draft", "verify.dispatch"} <= names
     s = eng.metrics.summary()
     assert s["drafted_tokens"] > 0
-    assert s["verify_time_s"] > 0 and s["draft_time_s"] > 0
-    txt = prometheus_text(s, tr)
-    assert "repro_serving_accept_rate" in txt
-    assert 'repro_serving_phase_seconds{phase="verify.device"}' in txt
+    assert s["verify_dispatch_s"] > 0 and s["draft_time_s"] > 0
