@@ -3,10 +3,10 @@
 One decode step attends each slot's single query against a cache scattered
 across a global page pool; one prefill chunk attends a *block of causal
 queries* against the same pages.  The page table is a *scalar-prefetch*
-operand (``pltpu.PrefetchScalarGridSpec``): BlockSpec index maps read it to
-decide which physical page to DMA into VMEM for each grid step, so HBM
-traffic is ``pages_held``, not ``slots x max_pages`` — the whole point of
-paging.
+operand (``pltpu.PrefetchScalarGridSpec``): BlockSpec index maps (in the
+per-head decode kernel, the kernel's own copies) read it to decide which
+physical page to DMA into VMEM, so HBM traffic is ``pages_held``, not
+``slots x max_pages`` — the whole point of paging.
 
 Decode kernels, one per page geometry (see ``repro.serving.layouts``):
 
@@ -40,15 +40,27 @@ engine's ``paged_prefill_apply`` / ``lm_paged_verify`` path):
     against ckv/krope pages, output stays latent (the caller up-projects
     through W_uv) — per-head K/V are never materialized.
 
-Grid: ``(slots | KV[, n_q_blocks], n_table)`` with the page dimension
-sequential ("arbitrary"); the online-softmax state (m, l, acc) lives in
-VMEM scratch and carries across a slot's pages, exactly like the kv-block
-dimension of ``flash_attention``.  Pages past a slot's valid cells are
-skipped at grid level (``pl.when``) — their table entries point at the
-trash page (page 0) and cost no MXU cycles.  Prefill additionally skips
-(a) whole query blocks past the chunk's ``n_valid`` tail (a mostly-empty
-bucket no longer pays full attention tiles for its padding rows) and
-(b) pages past each query block's causal horizon.
+Decode walk: the grid is ``(slots,)``.  A step reads its slot's length,
+copies only the pages that hold its cells (``pltpu.make_async_copy`` from
+the pool left in HBM, the page id read from the prefetched table), and
+walks them in blocks of ``ppb`` pages: one copy moves all KV heads of a
+page, block i+1 is copied while block i is scored (two halves of a VMEM
+buffer), and the walk ends at the last held page.  An empty slot costs a
+step and no copy; a table column past the length is never read.  ``ppb``
+follows from the shapes (about 256 cells a block, as many as fit a VMEM
+budget, at most the table), and a tail block's cells past the held pages
+are masked like cells past the length.  The online-softmax state (m, l,
+acc) for every KV head lives in VMEM scratch across the blocks.
+
+Prefill grid: ``(KV, n_q_blocks, n_table)`` with the page dimension
+sequential ("arbitrary") and the online-softmax state carried in VMEM
+scratch, like the kv-block dimension of ``flash_attention``; the MLA
+decode grid is ``(slots, n_table)`` the same way.  Pages past the valid
+cells are skipped at grid level (``pl.when``) — their table entries point
+at the trash page (page 0) and cost no MXU cycles.  Prefill additionally
+skips (a) whole query blocks past the chunk's ``n_valid`` tail (a
+mostly-empty bucket no longer pays full attention tiles for its padding
+rows) and (b) pages past each query block's causal horizon.
 
 Layouts (see ref.py): q [slots, KV, G, hd] (prefill: [S, KV, G, hd]);
 k/v pages [P, KV, ps, hd] (head-major); q_lat [slots, H, R]; ckv pages
@@ -58,9 +70,10 @@ n_valid).
 
 Block shapes obey the TPU rule that the last two dims of a block are
 divisible by (8, 128) or equal the array's: a k/v page block is
-``(1, 1, ps, hd)``, whose last two dims are the page's own, so any
-``page_size`` tiles (Mosaic pads a page shorter than the dtype's sublane
-tile; ``tests/test_tpu_compile.py`` compiles int8 pages of 16 rows);
+``(1, 1, ps, hd)`` (a decode page copy ``[KV, ps, hd]``), whose last two
+dims are the page's own, so any ``page_size`` tiles (Mosaic pads a page
+shorter than the dtype's sublane tile; ``tests/test_tpu_compile.py``
+compiles int8 pages of 16 rows);
 prefill queries are handed to the kernel head-major as [KV, S*G, hd] so
 one block is a contiguous ``(qb*G, hd)`` tile.
 
@@ -68,11 +81,13 @@ Quantized (int8) pools add ``k_scale``/``v_scale`` operands [P, KV, 1, ps]
 bf16 — one symmetric scale per (page, kv-head, offset) row, laid out as a
 lane vector so the block ``(1, 1, 1, ps)`` is legal and multiplies score
 columns directly — streamed through the same page-table index maps as
-their int8 data pages.  Dequant fuses into the online softmax: raw int8
-scores are multiplied by the key's scale per column, probabilities by the
-value's scale per row before the PV product — fp pages are never
-materialized, so HBM reads stay ~1/4 of the fp pool's.  The jnp oracles in ref.py apply the identical fused math
-(same multiply placement), which is what keeps quantized kernel-on vs
+their int8 data pages (decode gathers them by the table into one row of
+scales per block and kv-head, ``_block_scales``).  Dequant fuses into the
+online softmax: raw int8 scores are multiplied by the key's scale per
+column, probabilities by the value's scale per row before the PV product
+— fp pages are never materialized, so HBM reads stay ~1/4 of the fp
+pool's.  The jnp oracles in ref.py apply the identical fused math (same
+multiply placement), which is what keeps quantized kernel-on vs
 kernel-off token-identical.  MLA latent kernels take no scales (the
 layout seam rejects quantized latents — rank is a contracted dim).
 """
@@ -100,71 +115,134 @@ def _token_major_out(o, S: int):
     return o.reshape(KV, S, -1, hd).transpose(1, 0, 2, 3)
 
 
-def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                  scale: float, page_size: int, n_table: int, window: int,
+# Tokens walked per block, and the VMEM the double-buffered K and V blocks
+# may take: a quarter of the TPU's default 16 MiB scoped limit, which
+# leaves room for the fp32 upcasts of one head's block.
+_BLOCK_TOKENS = 256
+_BLOCK_VMEM = 4 * 2 ** 20
+
+
+def _pages_per_block(page_size: int, hd: int, KV: int, itemsize: int,
+                     n_table: int) -> int:
+    """Pages per block: about ``_BLOCK_TOKENS`` cells, no more than fit
+    ``_BLOCK_VMEM`` double-buffered for K and V, and no more than the
+    table holds."""
+    per_page = 2 * 2 * KV * page_size * hd * itemsize   # (K, V) x 2 halves
+    ppb = min(pl.cdiv(_BLOCK_TOKENS, page_size), _BLOCK_VMEM // per_page)
+    return max(1, min(ppb, n_table))
+
+
+def _block_scales(scales, page_table, ppb: int):
+    """[P, KV, 1, ps] scale pages -> [slots, n_blocks, KV, ppb * ps]: each
+    block's cells in a row per kv-head, the layout the kernel's scores
+    take.  Gathered here because Mosaic refuses to DMA one [KV, 1, ps]
+    scale page (a slice whose lane dim is under 128)."""
+    slots, n_table = page_table.shape
+    _, KV, _, ps = scales.shape
+    nb = pl.cdiv(n_table, ppb)
+    ids = jnp.pad(page_table, ((0, 0), (0, nb * ppb - n_table)))
+    x = scales[ids][..., 0, :].reshape(slots, nb, ppb, KV, ps)
+    return x.transpose(0, 1, 3, 2, 4).reshape(slots, nb, KV, ppb * ps)
+
+
+def _paged_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest, scale: float,
+                  page_size: int, n_table: int, ppb: int, window: int,
                   quantized: bool):
     if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        ks_ref, vs_ref, o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr = rest
     else:
         ks_ref = vs_ref = None
-        o_ref, m_scr, l_scr, acc_scr = rest
+        o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr = rest
     s = pl.program_id(0)
-    p = pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
+    KV = q_ref.shape[1]
+    hd = q_ref.shape[3]
+    bk = ppb * page_size
     length = len_ref[s]
-    base = p * page_size
-    # grid-level skip: cells entirely past the slot's valid tokens (for the
-    # ring the valid cell count saturates at the window — beyond that every
-    # cell holds a live in-window position)
+    # only the pages below the limit are walked (for the ring the valid
+    # cell count saturates at the window — beyond that every cell holds a
+    # live in-window position); table entries past them are never read
     limit = length if window == 0 else jnp.minimum(length, window)
+    n_pages = jnp.minimum(pl.cdiv(limit, page_size), n_table)
+    n_blocks = pl.cdiv(n_pages, ppb)
 
-    @pl.when(base < limit)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)               # [G, hd]
-        k = k_ref[0, 0].astype(jnp.float32)               # [ps, hd]
-        v = v_ref[0, 0].astype(jnp.float32)               # [ps, hd]
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # [G, ps]
-        if quantized:
-            # fused dequant: raw int8 scores scaled per key column
-            sc = sc * ks_ref[0, 0].astype(jnp.float32)    # [1, ps]
-        idx = base + jax.lax.broadcasted_iota(
-            jnp.int32, sc.shape, 1)                       # cell indices
+    def dma(i, half, op):
+        """Start or wait block i's copies into ``half`` of the double
+        buffer: one K and one V copy per held page, all KV heads each."""
+        for j in range(ppb):
+            col = i * ppb + j
+
+            @pl.when(col < n_pages)
+            def _():
+                page = pt_ref[s, col]
+                for n, (src, dst) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf))):
+                    cp = pltpu.make_async_copy(src.at[page], dst.at[half, j],
+                                               sems.at[n, half])
+                    getattr(cp, op)()
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        dma(0, 0, "start")
+
+    def block(i, carry):
+        half = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n_blocks)
+        def _next():
+            dma(i + 1, 1 - half, "start")
+
+        dma(i, half, "wait")
+        base = i * bk
+        idx = base + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         if window:
             # ring arithmetic: the cell's absolute position; p >= 0 is the
-            # window mask (and masks never-written cells of short slots)
+            # window mask (and masks never-written cells of short slots);
+            # a tail block's cells past the ring are no cells at all
             cur = length - 1
-            tok = cur - jnp.mod(cur - idx, window)
-            sc = jnp.where(tok >= 0, sc, NEG_INF)
+            valid = (idx < window) & (cur - jnp.mod(cur - idx, window) >= 0)
         else:
-            sc = jnp.where(idx < length, sc, NEG_INF)
+            valid = idx < length
+        # rows past the held pages hold whatever an earlier block or slot
+        # left in the buffer: zero them (and the value scales of masked
+        # cells), so that a masked probability never meets a NaN
+        held = (base + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+                < n_pages * page_size)
+        for h in range(KV):
+            q = q_ref[0, h].astype(jnp.float32)                   # [G, hd]
+            k = k_buf[half, :, h].astype(jnp.float32).reshape(bk, hd)
+            v = v_buf[half, :, h].astype(jnp.float32).reshape(bk, hd)
+            v = jnp.where(held, v, 0.0)
+            sc = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale       # [G, bk]
+            if quantized:
+                # fused dequant: raw int8 scores scaled per key column
+                sc = sc * ks_ref[0, i, h:h + 1].astype(jnp.float32)
+            sc = jnp.where(valid, sc, NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            pr = jnp.exp(sc - m_new)                              # [G, bk]
+            l_scr[h] = l_scr[h] * corr + jnp.sum(pr, axis=1, keepdims=True)
+            if quantized:
+                # fused dequant: probabilities scaled per value row (the
+                # softmax denominator stays unscaled — it normalizes
+                # probabilities, not values)
+                pr = pr * jnp.where(
+                    valid, vs_ref[0, i, h:h + 1].astype(jnp.float32), 0.0)
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+                pr, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
+        return carry
 
-        m_prev = m_scr[...]
-        l_prev = l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        pr = jnp.exp(sc - m_new)                          # [G, ps]
-        l_scr[...] = l_prev * corr + jnp.sum(pr, axis=1, keepdims=True)
-        # fused dequant: probabilities scaled per value row (the softmax
-        # denominator stays unscaled — it normalizes probabilities, not
-        # values)
-        pv = pr * vs_ref[0, 0].astype(jnp.float32) if quantized else pr
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            pv, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
-
-    @pl.when(p == n_table - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_scr[...] /
-                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+    o_ref[0] = (acc_scr[...] /
+                jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_attention_kernel(q, k_pages, v_pages, page_table, lengths, *,
@@ -173,47 +251,43 @@ def paged_attention_kernel(q, k_pages, v_pages, page_table, lengths, *,
     """q: [slots, KV, G, hd]; k/v_pages: [P, KV, ps, hd];
     page_table: [slots, n_table] int32; lengths: [slots] int32.
     ``window > 0`` selects the ring-cell position mapping.  ``k_scale``/
-    ``v_scale`` [P, KV, 1, ps] bf16 mark int8 pages — their blocks stream
-    through the same page-table index map and dequant fuses into the
-    softmax accumulation.
+    ``v_scale`` [P, KV, 1, ps] bf16 mark int8 pages — dequant fuses into
+    the softmax accumulation.
 
     Returns [slots, KV, G, hd] in q.dtype.
     """
     slots, KV, G, hd = q.shape
     ps = k_pages.shape[2]
     n_table = page_table.shape[1]
-    scale = hd ** -0.5
     quantized = k_scale is not None
+    ppb = _pages_per_block(ps, hd, KV, k_pages.dtype.itemsize, n_table)
 
-    kernel = functools.partial(_paged_kernel, scale=scale, page_size=ps,
-                               n_table=n_table, window=window,
-                               quantized=quantized)
-
-    page_spec = pl.BlockSpec((1, 1, ps, hd),
-                             lambda s, h, p, pt, ln: (pt[s, p], h, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, G, hd), lambda s, h, p, pt, ln: (s, h, 0, 0)),
-        # physical page chosen by the prefetched table — the paged gather
-        page_spec,
-        page_spec,
-    ]
+    kernel = functools.partial(_paged_kernel, scale=hd ** -0.5,
+                               page_size=ps, n_table=n_table, ppb=ppb,
+                               window=window, quantized=quantized)
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [pl.BlockSpec((1, KV, G, hd), lambda s, pt, ln: (s, 0, 0, 0)),
+                hbm, hbm]
     operands = [q, k_pages, v_pages]
     if quantized:
-        scale_spec = pl.BlockSpec((1, 1, 1, ps),
-                                  lambda s, h, p, pt, ln: (pt[s, p], h, 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
+        nb = pl.cdiv(n_table, ppb)
+        in_specs += [pl.BlockSpec((1, nb, KV, ppb * ps),
+                                  lambda s, pt, ln: (s, 0, 0, 0))] * 2
+        operands += [_block_scales(k_scale, page_table, ppb),
+                     _block_scales(v_scale, page_table, ppb)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(slots, KV, n_table),
+        grid=(slots,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, hd),
-                               lambda s, h, p, pt, ln: (s, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, G, hd), lambda s, pt, ln: (s, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),    # m
-            pltpu.VMEM((G, 1), jnp.float32),    # l
-            pltpu.VMEM((G, hd), jnp.float32),   # acc
+            pltpu.VMEM((2, ppb, KV, ps, hd), k_pages.dtype),   # K blocks
+            pltpu.VMEM((2, ppb, KV, ps, hd), v_pages.dtype),   # V blocks
+            pltpu.SemaphoreType.DMA((2, 2)),                   # (K, V) x half
+            pltpu.VMEM((KV, G, 1), jnp.float32),               # m
+            pltpu.VMEM((KV, G, 1), jnp.float32),               # l
+            pltpu.VMEM((KV, G, hd), jnp.float32),              # acc
         ],
     )
 
@@ -222,8 +296,9 @@ def paged_attention_kernel(q, k_pages, v_pages, page_table, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, KV, G, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="paged_attention",
     )(page_table, lengths, *operands)
 
 

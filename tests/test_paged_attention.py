@@ -23,42 +23,72 @@ from repro.kernels.paged_attention.ref import (paged_attention_ref,
                                                ring_positions)
 
 
-def _paged_case(rng, slots, H, KV, hd, ps, n, dtype):
-    """Random pool + disjoint per-slot page tables + random lengths."""
+def _paged_case(rng, slots, H, KV, hd, ps, n, dtype, lengths=None):
+    """Random pool + disjoint per-slot page tables + lengths (random in
+    [1, n * ps] unless given; a length past the table's cells, which only
+    the ring takes, holds every page)."""
     P = slots * n + 1                               # page 0 = trash
     q = jnp.asarray(rng.normal(size=(slots, H, hd)), dtype)
     kp = jnp.asarray(rng.normal(size=(P, KV, ps, hd)), dtype)
     vp = jnp.asarray(rng.normal(size=(P, KV, ps, hd)), dtype)
-    lengths = np.asarray(rng.integers(1, n * ps + 1, size=slots), np.int32)
+    if lengths is None:
+        lengths = rng.integers(1, n * ps + 1, size=slots)
+    lengths = np.asarray(lengths, np.int32)
     table = np.zeros((slots, n), np.int32)
     pid = 1
     for s in range(slots):
-        for i in range(-(-int(lengths[s]) // ps)):
+        for i in range(-(-min(int(lengths[s]), n * ps) // ps)):
             table[s, i] = pid
             pid += 1
     return q, kp, vp, jnp.asarray(table), jnp.asarray(lengths)
 
 
+# A table of 21 pages of 16 tokens: the kernel walks 16 such pages (256
+# tokens) per block at these widths, so it takes two blocks, the second a
+# tail of 5 pages
+MULTI_BLOCK = (3, 4, 2, 32, 16, 21, jnp.float32)
+
 PAGED_CASES = [
-    # (slots, H, KV, hd, ps, n_table, dtype)
-    (3, 4, 2, 32, 8, 4, jnp.float32),
-    (2, 8, 8, 16, 4, 3, jnp.float32),     # MHA (G = 1)
-    (1, 2, 1, 64, 16, 2, jnp.float32),    # MQA
-    (4, 4, 2, 32, 8, 1, jnp.float32),     # single-page table
-    (2, 4, 2, 32, 8, 3, jnp.bfloat16),    # bf16 i/o
+    # (slots, H, KV, hd, ps, n_table, dtype, lengths — None: random)
+    (3, 4, 2, 32, 8, 4, jnp.float32, None),
+    (2, 8, 8, 16, 4, 3, jnp.float32, None),     # MHA (G = 1)
+    (1, 2, 1, 64, 16, 2, jnp.float32, None),    # MQA
+    (4, 4, 2, 32, 8, 1, jnp.float32, None),     # single-page table
+    (2, 4, 2, 32, 8, 3, jnp.bfloat16, None),    # bf16 i/o
+    (*MULTI_BLOCK, None),                       # two blocks, a tail block
+    (4, *MULTI_BLOCK[1:], (256, 1, 336, 257)),  # block edges, 1 token, full
+    (8, *MULTI_BLOCK[1:], (1, 1, 1, 300, 1, 1, 1, 1)),  # mostly one token
 ]
 
 
-@pytest.mark.parametrize("slots,H,KV,hd,ps,n,dtype", PAGED_CASES)
-def test_paged_kernel_matches_ref(rng, slots, H, KV, hd, ps, n, dtype):
+def _case_id(case):
+    """The case's values joined by '-'; given lengths add a last part."""
+    *shape, dtype, lengths = case
+    parts = [*map(str, shape), dtype.__name__]
+    if lengths is not None:
+        parts.append("len" + "_".join(map(str, lengths)))
+    return "-".join(parts)
+
+
+def _cases(cases):
+    return [pytest.param(*c, id=_case_id(c)) for c in cases]
+
+
+@pytest.mark.parametrize("slots,H,KV,hd,ps,n,dtype,lengths",
+                         _cases(PAGED_CASES))
+def test_paged_kernel_matches_ref(rng, slots, H, KV, hd, ps, n, dtype,
+                                  lengths):
+    """Both page geometries: the contiguous table, and the same table as a
+    ring of ``n * ps`` cells that slots half a ring longer have wrapped."""
     q, kp, vp, table, lengths = _paged_case(rng, slots, H, KV, hd, ps, n,
-                                            dtype)
-    ref = paged_attention_ref(q, kp, vp, table, lengths)
-    out = paged_attention(q, kp, vp, table, lengths, use_kernel=True,
-                          interpret=True)
+                                            dtype, lengths)
     atol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), atol=atol)
+    for window, ln in ((0, lengths), (n * ps, lengths + n * ps // 2)):
+        ref = paged_attention_ref(q, kp, vp, table, ln, window=window)
+        out = paged_attention(q, kp, vp, table, ln, window=window,
+                              use_kernel=True, interpret=True)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32), atol=atol)
 
 
 def test_paged_ref_matches_ring_cache_decode(rng):
@@ -136,15 +166,16 @@ def _quantized_pages(kp, vp):
 
 
 @pytest.mark.parametrize("window", [0, 24], ids=["full", "ring"])
-@pytest.mark.parametrize("slots,H,KV,hd,ps,n,dtype", PAGED_CASES[:2])
+@pytest.mark.parametrize("slots,H,KV,hd,ps,n,dtype,lengths",
+                         _cases(PAGED_CASES[:2] + [(*MULTI_BLOCK, None)]))
 def test_paged_kernel_matches_ref_quantized(rng, slots, H, KV, hd, ps, n,
-                                            dtype, window):
+                                            dtype, lengths, window):
     """Int8 pages + per-row scales: the fused-dequant kernel must match the
     fused-dequant oracle on both page geometries (full and ring)."""
     if window and window != n * ps:
         window = n * ps
     q, kp, vp, table, lengths = _paged_case(rng, slots, H, KV, hd, ps, n,
-                                            dtype)
+                                            dtype, lengths)
     kq, vq, ks, vs = _quantized_pages(kp, vp)
     ref = paged_attention_ref(q, kq, vq, table, lengths, window=window,
                               k_scale=ks, v_scale=vs)
@@ -255,6 +286,38 @@ def test_trash_page_never_read(rng):
                               use_kernel=use_kernel, interpret=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(base),
                                    atol=2e-5)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("window", [0, 21 * 16], ids=["full", "ring"])
+def test_walk_stops_at_length(rng, window, quantized):
+    """The kernel reads no table entry past a slot's held pages: pointing
+    every slot's table tail at real pages full of NaN changes nothing."""
+    slots, H, KV, hd, ps, n, dtype = MULTI_BLOCK
+    q, kp, vp, table, lengths = _paged_case(rng, slots, H, KV, hd, ps, n,
+                                            dtype, (1, 256, 260))
+    held = -(-np.asarray(lengths) // ps)
+    P = kp.shape[0]
+    tails = np.asarray(table).copy()
+    for s in range(slots):
+        tails[s, held[s]:] = P + np.arange(n - held[s])
+    scales, nan_scales = {}, {}
+    if quantized:                  # int8 cannot hold NaN: its scales do
+        kp, vp, ks, vs = _quantized_pages(kp, vp)
+        scales = dict(k_scale=ks, v_scale=vs)
+        nan = jnp.full((n,) + ks.shape[1:], jnp.nan, ks.dtype)
+        nan_scales = {k: jnp.concatenate([x, nan]) for k, x in scales.items()}
+        tail = jnp.full((n,) + kp.shape[1:], 127, kp.dtype)
+    else:
+        tail = jnp.full((n,) + kp.shape[1:], jnp.nan, dtype)
+    kn, vn = jnp.concatenate([kp, tail]), jnp.concatenate([vp, tail])
+    ref = paged_attention_ref(q, kp, vp, table, lengths, window=window,
+                              **scales)
+    out = paged_attention(q, kn, vn, jnp.asarray(tails), lengths,
+                          window=window, use_kernel=True, interpret=True,
+                          **nan_scales)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 # ---------------------------------------------------------------------------
 # Chunked prefill (one request's bucketed chunk; rows >= n_valid are bucket

@@ -52,19 +52,19 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _pages(ps, dtype, quantized):
+def _pages(ps, dtype, quantized, slots=SLOTS, n_table=N_TABLE):
     """(k, v[, k_scale, v_scale]) head-major page shapes."""
-    P = SLOTS * N_TABLE + 1
+    P = slots * n_table + 1
     data = [((P, KV, ps, HD), I8 if quantized else dtype)] * 2
     scales = [((P, KV, 1, ps), BF16)] * 2 if quantized else []
     return data, scales
 
 
-def _decode(ps, dtype, quantized, window):
-    data, scales = _pages(ps, dtype, quantized)
-    n = WINDOW // ps if window else N_TABLE
-    shapes = ([((SLOTS, KV, G, HD), dtype)] + data
-              + [((SLOTS, n), I32), ((SLOTS,), I32)] + scales)
+def _decode(ps, dtype, quantized, window, slots=SLOTS, g=G, n_table=N_TABLE):
+    data, scales = _pages(ps, dtype, quantized, slots, n_table)
+    n = WINDOW // ps if window else n_table
+    shapes = ([((slots, KV, g, HD), dtype)] + data
+              + [((slots, n), I32), ((slots,), I32)] + scales)
 
     def fn(q, k, v, pt, ln, *sc):
         ks, vs = sc or (None, None)
@@ -118,9 +118,10 @@ def _flash(S=1024):
 
 # (id, builder) — builders return (fn, [(shape, dtype), ...]).  Page sizes
 # are the sublane tile of each storage dtype (8 f32 / 16 bf16 / 32 int8
-# rows), and, in the "-page16"/"-page8" cases, below it: a page block
-# (1, 1, ps, hd) spans the page's whole last two dims, so the TPU takes any
-# page size — int8 pools at ServeConfig's default page_size=16 included
+# rows), and, in the "-page16"/"-page8" cases, below it: a prefill page
+# block (1, 1, ps, hd), like a decode page copy [KV, ps, hd], spans the
+# page's whole last two dims, so the TPU takes any page size — int8 pools
+# at ServeConfig's default page_size=16 included
 CASES = [
     ("decode-bf16", lambda: _decode(16, BF16, False, 0)),
     ("decode-f32", lambda: _decode(16, jnp.float32, False, 0)),
@@ -139,6 +140,10 @@ CASES = [
     ("ring-prefill-int8-page16",
      lambda: _prefill(128, 16, BF16, True, WINDOW)),
     ("decode-bf16-page8", lambda: _decode(8, BF16, False, 0)),
+    # the nemo-chat benchmark cell's decode: 32 slots, G 4, a 256-page
+    # table of 16-token fp32 pages (16 pages a block, 16 blocks)
+    ("decode-f32-nemo-cell",
+     lambda: _decode(16, jnp.float32, False, 0, slots=32, g=4, n_table=256)),
     ("mla-decode", _mla_decode),
     ("mla-prefill", lambda: _mla_prefill(128)),
     ("flash", _flash),
