@@ -114,7 +114,7 @@ class Session:
     def __init__(self, model_cfg: ModelConfig,
                  mesh_cfg: Optional[MeshConfig] = None, *, seed: int = 0,
                  dp_mode: Optional[str] = None,
-                 allreduce: Optional[str] = None):
+                 allreduce: Optional[str] = None, params=None):
         model_cfg.validate()
         self.model = model_cfg
         self.mesh_cfg = mesh_cfg
@@ -125,6 +125,7 @@ class Session:
         self.bundle = registry.build(model_cfg)
         self._mesh = None
         self._params = None
+        self._init_params = params     # given at load: train starts here
         self._trainer = None
         self._trainer_key = None
         self._train_state = None
@@ -183,7 +184,8 @@ class Session:
     @property
     def params(self):
         """Current parameters: the trained state's params once ``train`` has
-        run, otherwise a seeded init (shared by serve/generate)."""
+        run, otherwise those given to ``load`` or a seeded init (shared by
+        serve/generate)."""
         if self._train_state is not None:
             return self._train_state.params
         if self._params is None:
@@ -193,10 +195,16 @@ class Session:
                 # initialize straight into the mesh layout: a full copy on
                 # one device would not fit beside its shards at real size
                 from repro.models import common
-                init = jax.jit(init, out_shardings=common.logical_to_mesh(
+                shardings = common.logical_to_mesh(
                     self.bundle.specs, self.mesh,
-                    common.rules_for(self.mesh_cfg, self.model)))
-            self._params = init(jax.random.PRNGKey(self.seed))
+                    common.rules_for(self.mesh_cfg, self.model))
+                init = jax.jit(init, out_shardings=shardings)
+            if self._init_params is not None:
+                self._params = jax.device_put(
+                    self._init_params,
+                    shardings if self.mesh_cfg is not None else None)
+            else:
+                self._params = init(jax.random.PRNGKey(self.seed))
         return self._params
 
     # -- train -------------------------------------------------------------
@@ -223,6 +231,8 @@ class Session:
         from repro.core.transparent import TransparentTrainer
         from repro.data.pipeline import make_input_pipeline
         from repro.data.readers import synthetic_tokens
+        from repro.obs import TRAIN_TRACER as tr
+        from repro.obs import TRAIN_TRACK
 
         opt = optimizer or OptimizerConfig(
             name="adam", lr=1e-3 if lr is None else lr)
@@ -272,7 +282,8 @@ class Session:
         if resume and ckpt_dir and latest_step(ckpt_dir) is not None:
             self._train_state, start = restore_elastic(ckpt_dir, trainer)
         elif self._train_state is None:
-            self._train_state = trainer.init(self.seed)
+            self._train_state = trainer.init(self.seed,
+                                             params=self._init_params)
             start = 0
         else:
             start = int(jax.device_get(self._train_state.step))
@@ -282,13 +293,23 @@ class Session:
         losses: List[float] = []
         t_start = time.time()
         step = start
+        tokens_per_step = global_batch * seq_len
         try:
-            for batch in it:
+            while True:
+                with tr.span("train.data", track=TRAIN_TRACK):
+                    batch = next(it, None)
+                if batch is None:
+                    break
                 t0 = time.time()
-                state, m = trainer.step(state, batch)
+                with tr.span("train.step", track=TRAIN_TRACK):
+                    state, m = trainer.step(state, batch)
                 monitor.record(time.time() - t0)
-                step = int(m["step"])
-                losses.append(float(m["loss"]))
+                with tr.span("train.sync", track=TRAIN_TRACK):
+                    step = int(m["step"])
+                    losses.append(float(m["loss"]))
+                tr.counter("train.steps", step, track=TRAIN_TRACK)
+                tr.counter("train.tokens", step * tokens_per_step,
+                           track=TRAIN_TRACK)
                 if log_every and (step % log_every == 0 or step == start + 1):
                     print(f"step {step:5d}  loss {losses[-1]:.4f}  "
                           f"gnorm {float(m['grad_norm']):.3f}", flush=True)
@@ -384,6 +405,12 @@ class Session:
         return eng
 
     @property
+    def train_state(self):
+        """The trained ``TrainState`` (params, optimizer state, step) of
+        the last ``train`` call (None before the first)."""
+        return self._train_state
+
+    @property
     def trainer(self):
         """The transparent trainer of the last ``train`` call (None
         before): its ``lower_step`` shows the compiled step."""
@@ -459,7 +486,7 @@ class Session:
 
 def load(arch: str, *, smoke: bool = False, mesh: MeshLike = None,
          seed: int = 0, dp_mode: Optional[str] = None,
-         allreduce: Optional[str] = None,
+         allreduce: Optional[str] = None, params=None,
          require: Iterable[str] = (), **overrides) -> Session:
     """The one supported entrypoint: ``load(arch) -> Session``.
 
@@ -469,6 +496,8 @@ def load(arch: str, *, smoke: bool = False, mesh: MeshLike = None,
                    the *only* distribution knob a user script needs;
     ``dp_mode`` / ``allreduce``  training placement / reduction strategy
                    (forwarded into the MeshConfig);
+    ``params``     initial parameters, a tree in the model's layout (default:
+                   a seeded init); training and serving start from them;
     ``require``    capability names that must be declared *now* (e.g.
                    ``("serve",)``) — fail at load, not mid-run;
     ``overrides``  ``ModelConfig.replace`` fields (``num_layers=2``, ...).
@@ -477,7 +506,7 @@ def load(arch: str, *, smoke: bool = False, mesh: MeshLike = None,
     if overrides:
         cfg = cfg.replace(**overrides)
     sess = Session(cfg, parse_mesh(mesh), seed=seed, dp_mode=dp_mode,
-                   allreduce=allreduce)
+                   allreduce=allreduce, params=params)
     for cap in require:
         sess._require(cap)
     return sess

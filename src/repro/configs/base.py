@@ -98,6 +98,9 @@ class ModelConfig:
     act: str = "swiglu"           # "swiglu" | "gelu" | "geglu" | "relu"
     norm: str = "rmsnorm"         # "rmsnorm" | "layernorm"
     rope_theta: float = 10_000.0
+    # share of each head's dims that RoPE turns (hf partial_rotary_factor);
+    # the rest pass through unrotated
+    rotary_fraction: float = 1.0
     tie_embeddings: bool = False
     scale_embed: bool = False     # gemma-style sqrt(d_model) embedding scaling
     norm_eps: float = 1e-6
@@ -129,13 +132,21 @@ class ModelConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // max(self.num_heads, 1))
 
+    @property
+    def rotary_dim(self) -> int:
+        """Leading dims of each head that RoPE rotates."""
+        return int(self.resolved_head_dim * self.rotary_fraction)
+
     def validate(self) -> None:
         assert self.family in FAMILIES, self.family
         assert self.attn_kind in ATTN_KINDS, self.attn_kind
         if self.family == "moe":
             assert self.moe is not None
         if self.attn_kind == "mla":
-            assert self.mla is not None
+            # MLA ropes its own qk_rope_head_dim slice whole
+            assert self.mla is not None and self.rotary_fraction == 1.0
+        assert 0.0 < self.rotary_fraction <= 1.0, self.rotary_fraction
+        assert self.rotary_dim % 2 == 0, self.rotary_dim
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

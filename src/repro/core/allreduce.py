@@ -138,6 +138,7 @@ def compressed_allreduce(grads, err, axes: Sequence[str]):
 # Dispatcher
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("allreduce")
 def reduce_gradients(grads, strategy: str, axes: Sequence[str],
                      bucket_bytes: int = 32 << 20, err=None):
     """Apply a reduction strategy; returns (grads, new_err_or_None)."""

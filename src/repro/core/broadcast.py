@@ -27,6 +27,7 @@ def _is_rank_zero(axes: Sequence[str]):
     return flag
 
 
+@jax.named_scope("broadcast")
 def broadcast_from_rank0(tree, axes: Sequence[str]):
     """Inside a shard_map manual region: replace every leaf with rank 0's
     (masked psum: only rank 0 contributes, everyone receives the sum)."""

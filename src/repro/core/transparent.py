@@ -96,6 +96,7 @@ def _linear_dp_rank(axes: Tuple[str, ...]):
     return r
 
 
+@jax.named_scope("allreduce")
 def _scatter_mean_vec(vec, axes: Tuple[str, ...], pad_to: int, dp: int):
     """reduce-scatter(mean) of a flat fp32 vector -> local [pad_to/dp] shard.
     Scattering over each axis in turn composes the sum in linear-rank
@@ -106,6 +107,7 @@ def _scatter_mean_vec(vec, axes: Tuple[str, ...], pad_to: int, dp: int):
     return v / dp
 
 
+@jax.named_scope("allreduce")
 def _gather_vec(shard, axes: Tuple[str, ...]):
     """Inverse of ``_scatter_mean_vec``'s scatter: the full flat vector."""
     for a in reversed(axes):
@@ -269,12 +271,19 @@ class TransparentTrainer:
 
     # -- init ------------------------------------------------------------------
 
-    def init(self, seed: int = 0):
-        """Materialize a broadcast-consistent TrainState on the mesh."""
+    def init(self, seed: int = 0, params=None):
+        """Materialize a broadcast-consistent TrainState on the mesh, from
+        ``params`` (a tree in the model's layout) where given, else from a
+        seeded init."""
         mesh_cfg = self.mesh_cfg
+        if params is not None:
+            params = jax.tree.map(
+                lambda x, s: jnp.asarray(x, s.dtype), params,
+                common.param_shape_structs(self.param_specs))
 
-        def _base_state(key):
-            params = common.init_params(self.param_specs, key)
+        def _base_state(key, given):
+            params = (common.init_params(self.param_specs, key)
+                      if given is None else given)
             err = (ar.init_error_tree(params)
                    if mesh_cfg.allreduce == "compressed" else None)
             return params, err
@@ -285,8 +294,8 @@ class TransparentTrainer:
             err_specs = (jax.tree.map(lambda s: s, pspecs)
                          if mesh_cfg.allreduce == "compressed" else None)
 
-            def _init_inner(key):
-                params, err = _base_state(key)
+            def _init_inner(key, given):
+                params, err = _base_state(key, given)
                 # paper §III-D.1: rank-0 broadcast guarantees identical replicas
                 params = bc.broadcast_from_rank0(params, self.dp_axes)
                 if self._zero1:
@@ -300,19 +309,18 @@ class TransparentTrainer:
 
             smapped = jax.shard_map(
                 _init_inner, mesh=self.mesh,
-                in_specs=(P(),),
+                in_specs=(P(), P()),
                 out_specs=TrainState(params=pspecs, opt=opt_specs,
                                      err=err_specs, step=P()),
                 check_vma=False, axis_names=self._manual_axes)
             fn = jax.jit(smapped, out_shardings=self.state_shardings())
-            return fn(jax.random.PRNGKey(seed))
         else:
-            def _init_auto(key):
-                params, err = _base_state(key)
+            def _init_auto(key, given):
+                params, err = _base_state(key, given)
                 return TrainState(params=params, opt=self.opt.init(params),
                                   err=err, step=jnp.zeros((), jnp.int32))
             fn = jax.jit(_init_auto, out_shardings=self.state_shardings())
-            return fn(jax.random.PRNGKey(seed))
+        return fn(jax.random.PRNGKey(seed), params)
 
     # -- the transparent step ----------------------------------------------------
 

@@ -98,8 +98,8 @@ def _project_qkv_rope(cfg, p, x, positions):
     q = _split_heads(q, H, hd)
     k = _split_heads(k, KV, hd)
     v = _split_heads(v, KV, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
+    k = apply_rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
     return q, k, v
 
 

@@ -272,9 +272,15 @@ def rope_frequencies(head_dim: int, theta: float):
     return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta: float = 10_000.0):
-    """x: [..., seq, heads, head_dim]; positions: [..., seq]."""
+def apply_rope(x, positions, theta: float = 10_000.0,
+               rotary_dim: Optional[int] = None):
+    """x: [..., seq, heads, head_dim]; positions: [..., seq].  Rotates the
+    first ``rotary_dim`` dims of each head (all of them by default) at the
+    frequencies of a ``rotary_dim``-wide head; the rest pass through."""
     hd = x.shape[-1]
+    if rotary_dim is not None and rotary_dim < hd:
+        rot = apply_rope(x[..., :rotary_dim], positions, theta)
+        return jnp.concatenate([rot, x[..., rotary_dim:]], axis=-1)
     freqs = jnp.asarray(rope_frequencies(hd, theta))           # [hd/2]
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # [..., seq, hd/2]
     cos = jnp.cos(angles)[..., :, None, :]                     # [..., seq, 1, hd/2]

@@ -83,7 +83,7 @@ def pixtral_prefill(cfg, params, tokens, patches):
         a, _ = attn.attention_apply(cfg, lp["attn"], h, positions)
         k = dot(h, lp["attn"]["wk"], cd).reshape(B, S, kv, hd)
         v = dot(h, lp["attn"]["wv"], cd).reshape(B, S, kv, hd)
-        k = attn.apply_rope(k, positions, cfg.rope_theta)
+        k = attn.apply_rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
         cache = _fill_kv_cache(k, v, positions, S)
         x = x + a
         h2 = apply_norm(cfg, lp["ln2"], x)

@@ -328,7 +328,7 @@ def rg_prefill(cfg, params, tokens, *, cache_len: int = 0, use_pallas=False):
                                     use_pallas=use_pallas)
         k = dot(h, p["attn"]["wk"], cd).reshape(B, S, cfg.num_kv_heads, -1)
         v = dot(h, p["attn"]["wv"], cd).reshape(B, S, cfg.num_kv_heads, -1)
-        k = attn.apply_rope(k, positions, cfg.rope_theta)
+        k = attn.apply_rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
         cache = _fill_kv_cache(k, v, positions, min(cache_len or S, win))
         x = x + a
         x = x + mlp_mod.mlp_apply(cfg, p["mlp"], apply_norm(cfg, p["ln2"], x))

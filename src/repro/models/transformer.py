@@ -176,7 +176,8 @@ def lm_forward(cfg, params, tokens, *, collect_cache: bool = False,
                     v = v + lp["attn"]["bv"].astype(cd)
                 k = k.reshape(B, S, kv, hd)
                 v = v.reshape(B, S, kv, hd)
-                k = attn.apply_rope(k, positions, cfg.rope_theta)
+                k = attn.apply_rope(k, positions, cfg.rope_theta,
+                                    cfg.rotary_dim)
                 cache_y = _fill_kv_cache(k, v, positions, cache_len, n_valid)
             x = x + a
             h2 = apply_norm(cfg, lp["ln2"], x)

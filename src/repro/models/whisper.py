@@ -179,7 +179,8 @@ def whisper_prefill(cfg, params, frames, tokens):
         if cfg.qkv_bias:
             k = k + lp["attn"]["bk"].astype(cd)
             v = v + lp["attn"]["bv"].astype(cd)
-        k = attn.apply_rope(k.reshape(B, S, KV, hd), positions, cfg.rope_theta)
+        k = attn.apply_rope(k.reshape(B, S, KV, hd), positions, cfg.rope_theta,
+                            cfg.rotary_dim)
         v = v.reshape(B, S, KV, hd)
         cache = _fill_kv_cache(k, v, positions, S)
         x = x + a

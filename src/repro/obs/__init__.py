@@ -7,7 +7,9 @@ the engine track, lifecycle spans per request, cache events from the page
 pool — exportable as a Perfetto-loadable Chrome trace or folded into
 ``ServingMetrics.summary()`` as flat per-phase seconds.  While a JAX
 profiler session records, the same spans also land on the profile as
-``serve.*`` annotations and in :func:`profiled`, whatever ``trace`` says.
+``serve.*`` annotations and in :func:`profiled`, whatever ``trace`` says;
+``Session.train``'s loop emits its ``train.*`` spans and counters there
+too (:data:`TRAIN_TRACER`).
 
 Import discipline: this package depends on the standard library and the
 profiler's TraceMe only (no numpy) — it sits below every serving module
@@ -18,10 +20,12 @@ from repro.obs.export import (HOST_OVERHEAD_FRAC, INFLIGHT_COUNTER,
                               TRACED_ONLY_KEYS, chrome_trace,
                               phase_coverage, phase_snapshot,
                               write_chrome_trace)
-from repro.obs.trace import (ENGINE_TRACK, NULL_TRACER, NullTracer, Profiled,
-                             Tracer, profiled, request_track)
+from repro.obs.trace import (ENGINE_TRACK, NULL_TRACER, TRAIN_TRACER,
+                             TRAIN_TRACK, NullTracer, Profiled, Tracer,
+                             profiled, request_track)
 
 __all__ = ["Tracer", "NullTracer", "NULL_TRACER", "ENGINE_TRACK",
+           "TRAIN_TRACER", "TRAIN_TRACK",
            "Profiled", "profiled",
            "request_track", "chrome_trace", "write_chrome_trace",
            "phase_snapshot", "phase_coverage",

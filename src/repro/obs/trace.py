@@ -82,8 +82,13 @@ profiling: Callable[[], bool] = _profiler.TraceMe.is_enabled
 #: the engine-loop track; phase attribution accumulates spans on it only
 ENGINE_TRACK = "engine"
 
-#: prefix of the TraceAnnotation each span becomes in a profiler session
+#: prefix of the TraceAnnotation each engine span becomes in a profiler
+#: session
 ANNOTATION_PREFIX = "serve."
+
+#: the training loop's track; its spans and counters carry their full
+#: ``train.*`` names
+TRAIN_TRACK = "train"
 
 #: event tuple layout (ph, name, track, ts, value, args) — ph follows the
 #: Chrome trace-event phase codes so the exporter is a dumb transcription
@@ -145,7 +150,8 @@ class _SpanCtx:
 
     def __enter__(self) -> "_SpanCtx":
         if profiling():
-            self._ann = TraceAnnotation(ANNOTATION_PREFIX + self._name)
+            self._ann = TraceAnnotation(self._tr.annotation_prefix
+                                        + self._name)
             self._ann.__enter__()
             self._p0 = time.perf_counter_ns()
         else:
@@ -171,6 +177,7 @@ class Tracer:
     (and, during a profiler session, the gated sink besides)."""
 
     enabled = True
+    annotation_prefix = ANNOTATION_PREFIX
 
     def __init__(self, *, clock: Optional[Callable[[], float]] = None,
                  capacity: int = 1 << 16,
@@ -305,6 +312,9 @@ class NullTracer:
     phase_seconds: Dict[str, float] = _EMPTY_DICT
     phase_counts: Dict[str, int] = _EMPTY_DICT
 
+    def __init__(self, annotation_prefix: str = ANNOTATION_PREFIX):
+        self.annotation_prefix = annotation_prefix
+
     def reset(self) -> None:
         pass
 
@@ -340,6 +350,10 @@ class NullTracer:
 
 #: the one NullTracer every disabled engine shares
 NULL_TRACER = NullTracer()
+
+#: the training loop's tracer (``Session.train``): it records only while a
+#: profiler session does, and its span names are the annotations' names
+TRAIN_TRACER = NullTracer(annotation_prefix="")
 
 
 def request_track(rid: int) -> str:

@@ -1,6 +1,6 @@
 """Engine tracing + per-phase attribution (repro.obs).
 
-Four layers of guarantees:
+Layers of guarantees:
   * tracer mechanics — deterministic timelines under an injectable clock,
     span open/close balance (including stale re-opens and mid-flight
     close_all), bounded ring buffer, engine-track-only phase accounting;
@@ -18,7 +18,11 @@ Four layers of guarantees:
     ``serve.*`` annotations, a constant offset from the process-wide
     record ``profiled()``, which holds the counters the benchmark reads;
     the per-request queue / prefill split is exact on a fake clock; the
-    model's layer kinds are named scopes in the lowered program.
+    model's layer kinds are named scopes in the lowered program;
+  * the training loop — ``Session.train``'s ``train.*`` spans balance and
+    its counters count under a profiler session, nothing is recorded
+    without one, and the injected all-reduce and the init broadcast are
+    named scopes of the lowered programs.
 """
 import json
 import re
@@ -557,3 +561,102 @@ def test_lowered_decode_scan_names_its_layer_kinds(dense_setup):
     op_names = " ".join(re.findall(r'op_name="([^"]*)"', hlo))
     for scope in ("attention", "mlp", "norm", "lm_head", "sampler"):
         assert f"/{scope}/" in op_names, scope
+
+
+# ---------------------------------------------------------------------------
+# The training loop's spans and counters
+# ---------------------------------------------------------------------------
+
+TRAIN_KW = dict(seq_len=16, global_batch=4)
+
+
+@pytest.fixture(scope="module")
+def profiled_train(tmp_path_factory):
+    """Three ``Session.train`` steps under a CPU profiler session (compiled
+    before it): the record they left in profiled(), and the train.* host
+    events of the .xplane.pb."""
+    from jax.profiler import ProfileData
+
+    from repro import api
+    session = api.load("stablelm-1.6b", smoke=True)
+    session.train(steps=1, **TRAIN_KW)
+    log_dir = tmp_path_factory.mktemp("prof_train")
+    profiled().clear()
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        session.train(steps=3, **TRAIN_KW)
+    finally:
+        jax.profiler.stop_trace()
+    events = list(profiled().events)
+    profiled().clear()
+    pd = ProfileData.from_file(str(next(log_dir.rglob("*.xplane.pb"))))
+    host = [e.name for plane in pd.planes
+            if plane.name.startswith("/host:CPU") for line in plane.lines
+            for e in line.events if e.name.startswith("train.")]
+    return events, host
+
+
+def test_train_spans_balance_and_counters_count(profiled_train):
+    """Each step is a data fetch, an enqueue and a sync, in that order and
+    apart; the counters give the state's step and the tokens it took."""
+    from repro.obs import TRAIN_TRACK
+    events, host = profiled_train
+    spans = sorted((e for e in events if e[0] == "X"), key=lambda e: e[3])
+    assert [e[1] for e in spans] == ["train.data", "train.step",
+                                     "train.sync"] * 3
+    assert all(e[2] == TRAIN_TRACK and e[4] > 0 for e in spans)
+    assert all(a[3] + a[4] <= b[3] for a, b in zip(spans, spans[1:]))
+    counters = {}
+    for e in events:
+        if e[0] == "C":
+            counters.setdefault(e[1], []).append(e[4])
+    assert counters == {"train.steps": [2.0, 3.0, 4.0],
+                        "train.tokens": [2.0 * 64, 3.0 * 64, 4.0 * 64]}
+    for name in ("train.data", "train.step", "train.sync"):
+        assert host.count(name) == 3, (name, host)
+
+
+def test_untraced_train_records_nothing(monkeypatch):
+    """No profiler session: the training loop creates no annotation and
+    records nothing."""
+    from repro import api
+    from repro.obs import trace as obs_trace
+    made = []
+
+    class Counting(obs_trace.TraceAnnotation):
+        def __init__(self, *a, **k):
+            made.append(a)
+            super().__init__(*a, **k)
+
+    monkeypatch.setattr(obs_trace, "TraceAnnotation", Counting)
+    profiled().clear()
+    api.load("stablelm-1.6b", smoke=True).train(steps=2, **TRAIN_KW)
+    assert made == [] and len(profiled().events) == 0
+
+
+def _op_names(hlo, op):
+    return [n for line in hlo.splitlines() if f" {op}(" in line
+            or f" {op}-start(" in line
+            for n in re.findall(r'op_name="([^"]*)"', line)]
+
+
+def test_lowered_dp_step_names_its_allreduce():
+    """The injected gradient reduction and the init broadcast run under
+    named scopes, so the device trace's op metadata names them."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro import api
+    from repro.configs.base import ShapeConfig
+    from repro.core import broadcast
+    from repro.launch.mesh import auto_mesh
+    session = api.load("stablelm-1.6b", smoke=True, mesh="4x1")
+    session.train(steps=1, **TRAIN_KW)
+    hlo = session.trainer.lower_step(session.bundle.train_input_specs(
+        ShapeConfig("t", "train", 16, 4))).compile().as_text()
+    assert any("/allreduce/" in n for n in _op_names(hlo, "all-reduce"))
+    mesh = auto_mesh((4, 1), ("data", "model"))
+    fn = jax.shard_map(lambda x: broadcast.broadcast_from_rank0(x, ("data",)),
+                       mesh=mesh, in_specs=P(), out_specs=P(),
+                       check_vma=False, axis_names={"data"})
+    hlo = jax.jit(fn).lower(jax.numpy.ones(8)).compile().as_text()
+    assert any("/broadcast/" in n for n in _op_names(hlo, "all-reduce"))

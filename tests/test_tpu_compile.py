@@ -4,7 +4,9 @@ No chip is needed: the TPU compiler compiles for a *described* ``v5e:2x2``
 topology, and refuses what the chip would refuse (block shapes the TPU
 cannot tile, too much VMEM) — which interpret-mode tests cannot see.  The
 widths are qwen2.5-14b's (KV 8, G 5, hd 128) for the per-head kernels and
-deepseek-v2-lite's (H 16, latent rank 512, rope 64) for MLA.
+deepseek-v2-lite's (H 16, latent rank 512, rope 64) for MLA.  The
+stablelm-dp4 benchmark cell's data-parallel train step compiles for the
+four chips at its published widths and fits their memory.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -28,14 +30,18 @@ BF16, I8, I32 = jnp.bfloat16, jnp.int8, jnp.int32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -158,3 +164,38 @@ def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, build):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
+
+
+#: one v5e chip's HBM (bench/peaks.json)
+HBM_BYTES = 16e9
+
+
+def test_dp4_train_step_compiles_for_v5e(topo, no_compile_cache):
+    """The stablelm-dp4 cell's step: stablelm-2-1.6b at its published
+    widths, 4 layers, replicated over a 4x1 mesh with the layerwise
+    all-reduce and Adam, one 4096-token sequence per chip."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    from repro.configs import get_config
+    from repro.configs.base import (MeshConfig, OptimizerConfig, RunConfig,
+                                    ShapeConfig)
+    from repro.core.transparent import TransparentTrainer
+    from repro.models import registry
+    cfg = get_config("stablelm-1.6b").replace(num_layers=4, attn_remat=True)
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    run = RunConfig(model=cfg, shape=ShapeConfig("c", "train", 4096, 4),
+                    mesh=MeshConfig(shape=(4, 1),
+                                    axis_names=("data", "model")),
+                    optimizer=OptimizerConfig(name="adam", lr=1e-3))
+    bundle = registry.build(cfg)
+    trainer = TransparentTrainer.from_bundle(run, bundle, mesh=mesh)
+    compiled = trainer.lower_step(
+        bundle.train_input_specs(run.shape)).compile()
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert held < HBM_BYTES, held
+    hlo = compiled.as_text()
+    assert "all-reduce" in hlo and "/allreduce/" in hlo
