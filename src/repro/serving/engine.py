@@ -358,6 +358,11 @@ class ServingEngine:
         self._inflight: Optional[_InFlight] = None
         self._pending: Dict[int, int] = {}              # rid -> tokens in flight
         self._last_toks_dev = jnp.zeros((self.cfg.max_batch,), jnp.int32)
+        # decode dispatches that took the sampled scan, and those of them
+        # in which some row's top_k > 0 runs the sampler's top-k search:
+        # the tracer's sampler.* counters
+        self._sampled_dispatches = 0
+        self._topk_dispatches = 0
         self.prefill_compiles = 0         # lifetime (metrics.reset survives)
         # speculative decoding: per-request n-gram drafters plus the slots
         # whose verify is in flight (those slots must not decode, draft
@@ -1156,6 +1161,10 @@ class ServingEngine:
                                  if s in self.pool.owner else GREEDY)
                      for s in range(cfg.max_batch)])
                 samp_dev = jnp.asarray(samp)
+                self._sampled_dispatches += 1
+                self._topk_dispatches += int((samp[:, 2] > 0).any())
+            tr.counter("sampler.sampled_dispatches", self._sampled_dispatches)
+            tr.counter("sampler.topk_dispatches", self._topk_dispatches)
             with tr.span("decode.dispatch", steps=cfg.decode_steps,
                          rows=len(plan.rows), sampled=sampled):
                 if self.paged:

@@ -86,6 +86,55 @@ def pack_params(p: SamplingParams) -> np.ndarray:
     ], dtype=np.int32)
 
 
+def _top_threshold(key, target, weight=None):
+    """Per row, the largest uint32 ``t`` whose tail — the sum of
+    ``weight`` (1 where None) over the row's ``key >= t`` — still reaches
+    ``target``; 0 when not even the whole row does.
+
+    The tail only shrinks as ``t`` rises, so fixing ``t``'s bits from the
+    top down, each kept iff the tail still reaches the target, is an exact
+    bisection: 32 masked reductions over the row and no sort.
+    """
+    def bit(i, t):
+        cand = t | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        hit = key >= cand[:, None]
+        tail = jnp.sum(hit if weight is None else jnp.where(hit, weight, 0),
+                       axis=-1)
+        return jnp.where(tail >= target, cand, t)
+    return jax.lax.fori_loop(0, 32, bit,
+                             jnp.zeros(key.shape[:-1], jnp.uint32))
+
+
+def _top_k_filter(scaled, top_k):
+    """Keep each row's ``top_k`` largest values (0 keeps all); ties with
+    the k-th survive.  The k-th is found on an order-preserving uint32
+    key of the f32 value (negatives and -inf included), then compared as
+    a float so that -0.0 and 0.0 tie as they do in a sort."""
+    bits = jax.lax.bitcast_convert_type(scaled, jnp.uint32)
+    neg = (bits >> 31) == 1
+    key = jnp.where(neg, ~bits, bits | jnp.uint32(1 << 31))
+    k = jnp.clip(top_k, 0, scaled.shape[-1])
+    t = _top_threshold(key, k)
+    kth = jax.lax.bitcast_convert_type(
+        jnp.where(t >> 31 == 1, t & jnp.uint32(0x7fffffff), ~t), jnp.float32)
+    return jnp.where((scaled >= kth[:, None]) | (k <= 0)[:, None],
+                     scaled, -jnp.inf)
+
+
+def _top_p_filter(scaled, top_p):
+    """Nucleus filter: a token of probability v stays iff the mass of the
+    tokens more probable than v is below ``top_p`` (so the most probable
+    always stays, and ties stay or go together); ``top_p >= 1`` keeps
+    every token.  Probabilities are >= 0, so their bit patterns order as
+    they do: the smallest kept pattern is the largest ``t`` whose tail
+    ``bits >= t`` still holds ``top_p`` of the mass."""
+    probs = jax.nn.softmax(scaled, axis=-1)
+    bits = jax.lax.bitcast_convert_type(probs, jnp.uint32)
+    t = _top_threshold(bits, top_p, weight=probs)
+    keep = (bits >= t[:, None]) | (top_p >= 1.0)[:, None]
+    return jnp.where(keep, scaled, -jnp.inf)
+
+
 @jax.named_scope("sampler")
 def sample_tokens(logits, packed, idx):
     """Sample one token per row.  Pure function of (logits, packed, idx).
@@ -98,9 +147,11 @@ def sample_tokens(logits, packed, idx):
     Returns [S] int32 tokens.  Rows with temperature == 0 take the
     argmax path (bit-identical to the greedy scan); the filters follow
     the usual order: temperature scale -> top-k -> top-p -> categorical.
-    The filter+draw pipeline (two vocab sorts, softmax, per-row threefry)
-    is several times the cost of the forward it follows on small models,
-    so it sits behind a ``lax.cond``: an all-greedy call — every
+    Each filter finds its cutoff by an exact per-row threshold search
+    (``_top_threshold``), not a vocabulary sort, and runs only when some
+    row asks for it.  The draw (a categorical over the kept logits,
+    per-row threefry) still costs passes over every row, so the whole
+    pipeline sits behind a ``lax.cond``: an all-greedy call — every
     speculative verify of greedy traffic — pays for the argmax only.
     """
     logits = logits.astype(jnp.float32)
@@ -111,25 +162,12 @@ def sample_tokens(logits, packed, idx):
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def sampled_rows(_):
-        V = logits.shape[-1]
         scaled = logits / jnp.maximum(temp, 1e-6)[:, None]
-        # top-k: threshold at the k-th largest value (ties past k survive
-        # — deterministic either way, which is all reproducibility needs)
-        desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-        k = jnp.clip(top_k, 0, V)
-        kth = jnp.take_along_axis(desc, jnp.maximum(k - 1, 0)[:, None],
-                                  axis=-1)
-        scaled = jnp.where((scaled >= kth) | (k <= 0)[:, None],
-                           scaled, -jnp.inf)
-        # top-p over the post-k distribution: keep the smallest prefix of
-        # the sorted probs whose mass reaches top_p (the first always stays)
-        probs = jax.nn.softmax(scaled, axis=-1)
-        p_desc = jnp.sort(probs, axis=-1)[:, ::-1]
-        cum = jnp.cumsum(p_desc, axis=-1)
-        kept = (cum - p_desc) < top_p[:, None]
-        cutoff = jnp.min(jnp.where(kept, p_desc, jnp.inf), axis=-1)
-        keep = (probs >= cutoff[:, None]) | (top_p >= 1.0)[:, None]
-        scaled = jnp.where(keep, scaled, -jnp.inf)
+        scaled = jax.lax.cond(jnp.any(top_k > 0), _top_k_filter,
+                              lambda s, _: s, scaled, top_k)
+        # top-p over the post-k distribution
+        scaled = jax.lax.cond(jnp.any(top_p < 1.0), _top_p_filter,
+                              lambda s, _: s, scaled, top_p)
 
         def draw(seed_i, idx_i, row):
             key = jax.random.fold_in(jax.random.PRNGKey(seed_i), idx_i)

@@ -11,12 +11,15 @@ deterministically replays the engine's own sampler at each drafted
 position, so spec-on output is token-identical to spec-off, greedy and
 sampled alike.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import MeshConfig, ServeConfig, get_config
 from repro.serving import GREEDY, SamplingParams, ServingEngine
-from repro.serving.sampling import pack_params, sample_tokens
+from repro.serving.sampling import (_top_k_filter, _top_p_filter,
+                                    pack_params, sample_tokens)
 from repro.serving.spec import NGramDrafter
 
 ARCHS = {
@@ -116,6 +119,160 @@ def test_sample_tokens_greedy_and_determinism():
     x = np.asarray(sample_tokens(flat, low_t, idx))
     y = np.asarray(sample_tokens(flat, low_t, idx + 17))
     assert (x != y).any()
+
+
+@jax.jit
+def _sort_reference(logits, packed, idx):
+    """The sort-based sampler the threshold search replaced, kept verbatim
+    as the reference: (filtered scaled logits, tokens)."""
+    logits = logits.astype(jnp.float32)
+    temp = jax.lax.bitcast_convert_type(packed[:, 0], jnp.float32)
+    top_p = jax.lax.bitcast_convert_type(packed[:, 1], jnp.float32)
+    top_k = packed[:, 2]
+    seed = packed[:, 3]
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    V = logits.shape[-1]
+    scaled = logits / jnp.maximum(temp, 1e-6)[:, None]
+    # top-k: threshold at the k-th largest value (ties past k survive
+    # — deterministic either way, which is all reproducibility needs)
+    desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    k = jnp.clip(top_k, 0, V)
+    kth = jnp.take_along_axis(desc, jnp.maximum(k - 1, 0)[:, None],
+                              axis=-1)
+    scaled = jnp.where((scaled >= kth) | (k <= 0)[:, None],
+                       scaled, -jnp.inf)
+    # top-p over the post-k distribution: keep the smallest prefix of
+    # the sorted probs whose mass reaches top_p (the first always stays)
+    probs = jax.nn.softmax(scaled, axis=-1)
+    p_desc = jnp.sort(probs, axis=-1)[:, ::-1]
+    cum = jnp.cumsum(p_desc, axis=-1)
+    kept = (cum - p_desc) < top_p[:, None]
+    cutoff = jnp.min(jnp.where(kept, p_desc, jnp.inf), axis=-1)
+    keep = (probs >= cutoff[:, None]) | (top_p >= 1.0)[:, None]
+    scaled = jnp.where(keep, scaled, -jnp.inf)
+
+    def draw(seed_i, idx_i, row):
+        key = jax.random.fold_in(jax.random.PRNGKey(seed_i), idx_i)
+        return jax.random.categorical(key, row)
+
+    sampled = jax.vmap(draw)(seed, idx.astype(jnp.int32), scaled)
+    return scaled, jnp.where(temp > 0.0, sampled.astype(jnp.int32), greedy)
+
+
+@jax.jit
+def _threshold_filters(logits, packed):
+    """The new sampler's filters, exposed: (after top-k, after top-p)."""
+    temp = jax.lax.bitcast_convert_type(packed[:, 0], jnp.float32)
+    top_p = jax.lax.bitcast_convert_type(packed[:, 1], jnp.float32)
+    after_k = _top_k_filter(logits / jnp.maximum(temp, 1e-6)[:, None],
+                            packed[:, 2])
+    return after_k, _top_p_filter(after_k, top_p)
+
+
+_sample_jit = jax.jit(sample_tokens)
+
+#: a token whose exact mass above it lies this close to top_p may fall on
+#: either side of the nucleus edge: both samplers sum f32 probabilities,
+#: in different orders
+BOUNDARY_TOL = 1e-6
+
+V_SMALL = 1000
+
+
+def _sp(t, p=1.0, k=0):
+    return (t, p, k)
+
+
+# id -> (vocab, rows, logits kind, per-row (temperature, top_p, top_k)
+# cycled over the rows); every row gets its own seed
+EXACT_CASES = {
+    **{f"t{t}-p{p}": (V_SMALL, 128, "normal", [_sp(t, p)])
+       for t in (0.3, 0.8, 2.0) for p in (0.5, 0.9, 0.95, 1.0)},
+    **{f"k{k}": (V_SMALL, 128, "normal", [_sp(0.8, 1.0, k)])
+       for k in (1, 8, V_SMALL, V_SMALL + 5)},
+    "k8-p0.9": (V_SMALL, 128, "normal", [_sp(0.8, 0.9, 8)]),
+    "kV-p0.5": (V_SMALL, 128, "normal", [_sp(2.0, 0.5, V_SMALL)]),
+    "k64-p0.95": (V_SMALL, 128, "normal", [_sp(0.3, 0.95, 64)]),
+    "greedy-mixed": (V_SMALL, 128, "normal",
+                     [_sp(0.0), _sp(0.8, 0.95), _sp(0.3, 1.0, 8),
+                      _sp(2.0, 0.5, 1), _sp(0.8, 0.9, V_SMALL + 5)]),
+    "ties": (V_SMALL, 128, "ties",
+             [_sp(0.8, 0.9, 8), _sp(2.0, 0.5), _sp(0.3, 0.95, 3),
+              _sp(0.8, 1.0, 1), _sp(0.0)]),
+    "neg-inf": (V_SMALL, 128, "neg_inf",
+                [_sp(0.8, 0.9), _sp(2.0, 1.0, 8), _sp(0.3, 0.5, 40),
+                 _sp(0.8, 0.95, V_SMALL), _sp(0.0)]),
+    # the nemo-chat cell's shape and mix: 32 slots of a 131072-token
+    # vocabulary, T 0.8 and top_p 0.95, a greedy tenth
+    "full-32x131072": (131072, 32, "normal",
+                       [_sp(0.0)] + [_sp(0.8, 0.95)] * 9),
+}
+
+
+def _case_inputs(name):
+    V, rows, kind, params = EXACT_CASES[name]
+    rng = np.random.default_rng(sorted(EXACT_CASES).index(name))
+    logits = rng.normal(size=(rows, V)).astype(np.float32)
+    if kind == "ties":
+        # half the rows flat, the rest on a coarse grid: long runs of
+        # repeated values straddle every cutoff
+        logits = np.round(logits * 2) / 2
+        logits[::2] = 0.0
+        logits[1::4] = np.round(logits[1::4])
+    elif kind == "neg_inf":
+        logits[rng.random(logits.shape) < 0.3] = -np.inf
+        # rows with only a handful of finite entries, fewer than top_k
+        logits[::3, 5:] = -np.inf
+    packed = np.stack([
+        pack_params(SamplingParams(temperature=t, top_p=p, top_k=k,
+                                   seed=1000 + r))
+        for r, (t, p, k) in zip(range(rows),
+                                params * (rows // len(params) + 1))])
+    idx = np.arange(rows, dtype=np.int32) + 100
+    return logits, packed, idx
+
+
+def _boundary_rows(probs, ref_keep, new_keep, top_p):
+    """Rows whose keep masks differ, each checked to differ only at tokens
+    whose exact mass above them lies within BOUNDARY_TOL of top_p."""
+    rows = []
+    for r in np.nonzero((ref_keep != new_keep).any(-1))[0]:
+        p64 = probs[r].astype(np.float64)
+        desc = np.sort(p64)[::-1]
+        above = np.concatenate([[0.0], np.cumsum(desc)])
+        for v in p64[ref_keep[r] != new_keep[r]]:
+            mass = above[np.searchsorted(-desc, -v, side="left")]
+            assert abs(mass - top_p[r]) <= BOUNDARY_TOL, (
+                f"row {r}: token of prob {v} has mass {mass} above it, "
+                f"not within {BOUNDARY_TOL} of top_p {top_p[r]}")
+        rows.append(int(r))
+    return rows
+
+
+@pytest.mark.parametrize("case", list(EXACT_CASES))
+def test_threshold_sampler_matches_sort_reference(case):
+    """The threshold search keeps what the two vocabulary sorts kept and
+    draws the same tokens: top-k exactly (integer counts), top-p up to f32
+    rounding at the nucleus edge, reported and held under 1% of rows."""
+    logits, packed, idx = _case_inputs(case)
+    top_p = packed[:, 1].view(np.float32)
+    ref_scaled, ref_tok = map(np.asarray,
+                              _sort_reference(logits, packed, idx))
+    after_k, new_scaled = map(np.asarray, _threshold_filters(logits, packed))
+    # top-k alone: the reference with every top_p at 1 against the search
+    no_p = packed.copy()
+    no_p[:, 1] = np.float32(1.0).view(np.int32)
+    ref_k = np.asarray(_sort_reference(logits, no_p, idx)[0])
+    assert np.array_equal(ref_k == -np.inf, after_k == -np.inf)
+    probs = np.asarray(jax.nn.softmax(jnp.asarray(after_k), axis=-1))
+    boundary = _boundary_rows(probs, ref_scaled != -np.inf,
+                              new_scaled != -np.inf, top_p)
+    print(f"{case}: boundary-rounding rows {boundary} of {len(logits)}")
+    assert len(boundary) < 0.01 * len(logits), boundary
+    tok = np.asarray(_sample_jit(logits, packed, idx))
+    rest = np.setdiff1d(np.arange(len(logits)), boundary)
+    assert np.array_equal(tok[rest], ref_tok[rest])
 
 
 def test_ngram_drafter_proposes_continuations():
@@ -239,6 +396,39 @@ def test_greedy_params_identical_to_default_path():
     mixed = e3.generate([prompts[0], prompts[1]], 5,
                         sampling=[None, SAMPLED])
     assert mixed[0] == plain[0]
+
+
+def test_sampler_dispatch_counters():
+    """sampler.sampled_dispatches counts the decode dispatches that take
+    the sampled scan, sampler.topk_dispatches those in which some row's
+    top_k > 0 runs the top-k search; greedy traffic leaves both at 0."""
+    cfg = _cfg("full")
+    rng = np.random.default_rng(9)
+    prompts = _prompts(rng, cfg.vocab_size, [7, 12])
+
+    def counts(sampling):
+        eng = _engine(cfg, trace=True, enable_spec=False)
+        eng.generate(prompts, 5, sampling=sampling)
+        evs = eng.tracer.events
+        last = {e[1]: e[4] for e in evs if e[0] == "C"
+                and e[1].startswith("sampler.")}
+        sampled = sum(1 for e in evs if e[0] == "X"
+                      and e[1] == "decode.dispatch" and e[5]["sampled"])
+        return last, sampled
+
+    last, sampled = counts(None)
+    assert last == {"sampler.sampled_dispatches": 0,
+                    "sampler.topk_dispatches": 0} and sampled == 0
+    last, sampled = counts([SamplingParams(temperature=0.8, top_p=0.9,
+                                           seed=1), None])
+    assert sampled > 0
+    assert last == {"sampler.sampled_dispatches": sampled,
+                    "sampler.topk_dispatches": 0}
+    last, sampled = counts([SamplingParams(temperature=0.8, top_k=8,
+                                           seed=1), None])
+    assert sampled > 0
+    assert last == {"sampler.sampled_dispatches": sampled,
+                    "sampler.topk_dispatches": sampled}
 
 
 # ---------------------------------------------------------------------------

@@ -199,3 +199,20 @@ def test_dp4_train_step_compiles_for_v5e(topo, no_compile_cache):
     assert held < HBM_BYTES, held
     hlo = compiled.as_text()
     assert "all-reduce" in hlo and "/allreduce/" in hlo
+
+
+def test_sampler_compiles_without_sort(one_chip, no_compile_cache):
+    """The nemo-chat cell's sampler, 32 slots of a 131072-token
+    vocabulary: the top-k and top-p cutoffs come from threshold searches,
+    so the program holds no sort.  The sampling parameters are operands,
+    so this one program serves top-p rows and top-k rows alike: its
+    three conditionals (sampled or greedy, top-k, top-p) are all here."""
+    import re
+
+    from repro.serving.sampling import PACKED_WIDTH, sample_tokens
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (((32, 131072), jnp.float32),
+                         ((32, PACKED_WIDTH), I32), ((32,), I32))]
+    hlo = jax.jit(sample_tokens).lower(*args).compile().as_text()
+    assert not re.search(r"\bsort\b", hlo)
+    assert len(re.findall(r"\bconditional\(", hlo)) >= 3
